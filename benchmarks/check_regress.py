@@ -11,16 +11,18 @@ job when a performance ratio regresses below its floor:
     pick must never lose to its own untuned baseline),
   * BENCH_serve.json — schema ``repro.serve.report.validate_serve``;
     continuous-vs-static throughput >= SERVE_SPEEDUP_FLOOR,
-  * BENCH_graph.json — schema v3: fused-vs-unfused HBM ratio >= the
+  * BENCH_graph.json — schema v4: fused-vs-unfused HBM ratio >= the
     modeled floor recorded in the document
     (``benchmarks.graph_fusion.HBM_RATIO_FLOOR``), *measured*
     merged-vs-sequential wall-clock speedup >= the document's
-    ``measured_floor`` (``MEASURED_SPEEDUP_FLOOR``, >= 1.2), bit
-    parity with both the explicit-schedule oracle and sequential
-    dispatch, AND a ``model_layer`` entry: the whole dense-family
-    layer graph must keep >= 1 merged group with its residual tap
-    exported, bit parity vs ``models.transformer.dense_layer_forward``,
-    and measured layer-forward speedup >= ``model_floor`` (>= 1.2).
+    ``measured_floor`` (``MEASURED_SPEEDUP_FLOOR``, >= 1.2), relative
+    error <= ``parity_rtol`` (<= 1e-5, see
+    ``graph_fusion.PARITY_RTOL``) against both the fp32
+    explicit-schedule oracle and sequential dispatch, AND a
+    ``model_layer`` entry: the whole dense-family layer graph must keep
+    >= 1 merged group with its residual tap exported, the same parity
+    bound vs ``models.transformer.dense_layer_forward``, and measured
+    layer-forward speedup >= ``model_floor`` (>= 1.2).
 
 The emitting benchmarks enforce their own gates too; this checker is
 the belt to their suspenders — it catches a stale or hand-edited
@@ -81,22 +83,26 @@ def check(problems: list) -> None:
         lfloor = graph.get("model_floor")
         chains = graph.get("chains")
         model = graph.get("model_layer")
-        if graph.get("version") != 3:
+        rtol = graph.get("parity_rtol")
+        if graph.get("version") != 4:
             problems.append(f"BENCH_graph.json: schema version "
-                            f"{graph.get('version')!r} != 3 (stale "
+                            f"{graph.get('version')!r} != 4 (stale "
                             f"artifact? re-run benchmarks.graph_fusion)")
         elif (not isinstance(floor, (int, float))
                 or not isinstance(mfloor, (int, float))
                 or not isinstance(lfloor, (int, float))
+                or not isinstance(rtol, (int, float))
                 or not isinstance(chains, list) or not chains
                 or not isinstance(model, dict)):
             problems.append("BENCH_graph.json: needs numeric 'floor', "
-                            "'measured_floor' and 'model_floor', "
+                            "'measured_floor', 'model_floor' and "
+                            "'parity_rtol', "
                             "non-empty 'chains' and a 'model_layer' "
                             "object")
-        elif mfloor < 1.2 or lfloor < 1.2:
+        elif mfloor < 1.2 or lfloor < 1.2 or rtol > 1e-5:
             problems.append(f"BENCH_graph.json: measured_floor {mfloor} "
-                            f"/ model_floor {lfloor} < 1.2 (the gates "
+                            f"/ model_floor {lfloor} < 1.2 or "
+                            f"parity_rtol {rtol} > 1e-5 (the gates "
                             f"must not be weakened)")
         else:
             for row in chains:
@@ -115,14 +121,15 @@ def check(problems: list) -> None:
                     problems.append(
                         f"BENCH_graph.json: {row.get('shape')} has no "
                         f"merged group (megakernel path not exercised)")
-                if row.get("bit_parity") is not True:
-                    problems.append(
-                        f"BENCH_graph.json: {row.get('shape')} lost bit "
-                        f"parity vs the explicit-schedule oracle")
-                if row.get("bit_parity_sequential") is not True:
-                    problems.append(
-                        f"BENCH_graph.json: {row.get('shape')} merged "
-                        f"kernel lost bit parity vs sequential dispatch")
+                for key, what in (("oracle_rel_err",
+                                   "the explicit-schedule oracle"),
+                                  ("sequential_rel_err",
+                                   "sequential dispatch")):
+                    err = row.get(key)
+                    if not isinstance(err, (int, float)) or err > rtol:
+                        problems.append(
+                            f"BENCH_graph.json: {row.get('shape')} "
+                            f"{key} {err} vs {what} > {rtol}")
             speedup = model.get("measured_speedup")
             if not isinstance(speedup, (int, float)) or speedup < lfloor:
                 problems.append(
@@ -136,14 +143,14 @@ def check(problems: list) -> None:
                 problems.append(
                     "BENCH_graph.json: model_layer exports no residual "
                     "tap")
-            if model.get("bit_parity") is not True:
-                problems.append(
-                    "BENCH_graph.json: model_layer lost bit parity vs "
-                    "models.transformer.dense_layer_forward")
-            if model.get("bit_parity_sequential") is not True:
-                problems.append(
-                    "BENCH_graph.json: model_layer merged kernel lost "
-                    "bit parity vs sequential dispatch")
+            for key, what in (("oracle_rel_err",
+                               "models.transformer.dense_layer_forward"),
+                              ("sequential_rel_err", "sequential dispatch")):
+                err = model.get(key)
+                if not isinstance(err, (int, float)) or err > rtol:
+                    problems.append(
+                        f"BENCH_graph.json: model_layer {key} {err} vs "
+                        f"{what} > {rtol}")
 
 
 def main() -> None:

@@ -5,21 +5,22 @@
 Gates (CI tier-1 smoke, PR 8 + ISSUE 9 + ISSUE 10):
   * the fused plan's HBM-bytes proxy beats the unfused pricing of the
     same chain by >= 1.3x (``GraphCostReport.hbm_ratio``),
-  * execution is bit-identical to the explicit-schedule oracle
-    (``repro.models.chains``) AND to sequential per-node dispatch
-    (``build(merge=False)``),
+  * execution matches the fp32 explicit-schedule oracle
+    (``repro.models.chains``) AND sequential per-node dispatch
+    (``build(merge=False)``) within ``PARITY_RTOL`` of the output scale,
   * the merged megakernel's *measured* wall clock (``tune/measure.py``
     harness: warmup + median-of-repeats around ``block_until_ready``)
     beats sequential dispatch by >= 1.2x,
   * the whole dense-family layer graph (``graph/from_model.py``) merges
     into one megakernel spanning attention and the MLP (residual tap
-    exported), stays bit-identical to
-    ``models.transformer.dense_layer_forward`` and to sequential
-    dispatch, and its measured layer-forward speedup clears >= 1.2x.
+    exported), matches ``models.transformer.dense_layer_forward`` and
+    sequential dispatch within ``PARITY_RTOL``, and its measured
+    layer-forward speedup clears >= 1.2x.
 
 ``--smoke`` runs the small shapes only; the full run adds larger ones.
-Emits ``BENCH_graph.json`` (schema v3: ``measured_speedup`` per chain
-plus the ``model_layer`` entry) at the repo root.
+Emits ``BENCH_graph.json`` (schema v4: ``measured_speedup`` and the
+oracle/sequential relative errors per chain plus the ``model_layer``
+entry) at the repo root.
 """
 from __future__ import annotations
 
@@ -37,6 +38,12 @@ HBM_RATIO_FLOOR = 1.3
 MEASURED_SPEEDUP_FLOOR = 1.2
 #: minimum measured whole-layer-forward speedup over sequential dispatch
 MODEL_SPEEDUP_FLOOR = 1.2
+#: max |error| / max |oracle| allowed between fp32 paths.  Merged,
+#: sequential and XLA execution sum the same products in different
+#: orders (Mosaic on the chip, XLA on the host), which moves fp32 results
+#: by about k * 2^-24 ~ 4e-6 of the output scale for these k <= 128
+#: dots; a bf16 stage would sit near 2^-8 ~ 4e-3.
+PARITY_RTOL = 1e-5
 #: calls per timed sample — amortizes timer granularity; the harness
 #: still takes the median over ``repeats`` samples
 CALLS_PER_SAMPLE = 10
@@ -58,6 +65,7 @@ def run_chain(lq, lkv, d, dv, f, *, repeats=7) -> dict:
     want = np.asarray(chains.attention_mlp_oracle(
         {k: v for k, v in ops.items()}))
     max_err = float(np.abs(got - want).max())
+    scale = float(np.abs(want).max())
 
     def loop(fn):
         def run():
@@ -80,8 +88,8 @@ def run_chain(lq, lkv, d, dv, f, *, repeats=7) -> dict:
         "cycles": rep.cycles,
         "cycles_unfused": rep.cycles_unfused,
         "merged_groups": list(acc.group_kernels),
-        "bit_parity": bool((got == want).all()),
-        "bit_parity_sequential": bool((got == got_seq).all()),
+        "oracle_rel_err": max_err / scale,
+        "sequential_rel_err": float(np.abs(got - got_seq).max()) / scale,
         "max_err": max_err,
         "t_merged_s": t_merged,
         "t_sequential_s": t_seq,
@@ -91,7 +99,7 @@ def run_chain(lq, lkv, d, dv, f, *, repeats=7) -> dict:
 
 def run_model_layer(l, d, dv, f, *, repeats=7) -> dict:
     """One dense-family transformer layer as a fused graph vs sequential
-    per-node dispatch, bit-compared against the model-side oracle."""
+    per-node dispatch, compared against the model-side fp32 oracle."""
     import repro
     from repro.graph import executor as graph_executor
     from repro.graph import from_model
@@ -106,6 +114,7 @@ def run_model_layer(l, d, dv, f, *, repeats=7) -> dict:
     got_seq = np.asarray(seq(ops))
     want = np.asarray(from_model.layer_oracle(ops))
     max_err = float(np.abs(got - want).max())
+    scale = float(np.abs(want).max())
 
     def loop(fn):
         def run():
@@ -128,8 +137,8 @@ def run_model_layer(l, d, dv, f, *, repeats=7) -> dict:
         "tapped_edges": list(rep.tapped_edges),
         "tap_hbm_bytes": rep.tap_hbm_bytes,
         "merged_groups": list(acc.group_kernels),
-        "bit_parity": bool((got == want).all()),
-        "bit_parity_sequential": bool((got == got_seq).all()),
+        "oracle_rel_err": max_err / scale,
+        "sequential_rel_err": float(np.abs(got - got_seq).max()) / scale,
         "max_err": max_err,
         "t_merged_s": t_merged,
         "t_sequential_s": t_seq,
@@ -159,8 +168,8 @@ def main(argv=None) -> None:
               f"measured {row['t_merged_s'] * 1e3:.2f}ms vs sequential "
               f"{row['t_sequential_s'] * 1e3:.2f}ms "
               f"({row['measured_speedup']:.2f}x), "
-              f"bit_parity={row['bit_parity']} "
-              f"(max_err={row['max_err']:.1e})")
+              f"oracle_rel_err={row['oracle_rel_err']:.1e} "
+              f"sequential_rel_err={row['sequential_rel_err']:.1e}")
 
     layer_shape = (32, 32, 32, 64) if args.smoke else (64, 64, 64, 128)
     model = run_model_layer(*layer_shape)
@@ -171,10 +180,11 @@ def main(argv=None) -> None:
           f"measured {model['t_merged_s'] * 1e3:.2f}ms vs sequential "
           f"{model['t_sequential_s'] * 1e3:.2f}ms "
           f"({model['measured_speedup']:.2f}x), "
-          f"bit_parity={model['bit_parity']} "
-          f"(max_err={model['max_err']:.1e})")
+          f"oracle_rel_err={model['oracle_rel_err']:.1e} "
+          f"sequential_rel_err={model['sequential_rel_err']:.1e}")
 
-    doc = {"version": 3, "floor": HBM_RATIO_FLOOR,
+    doc = {"version": 4, "floor": HBM_RATIO_FLOOR,
+           "parity_rtol": PARITY_RTOL,
            "measured_floor": MEASURED_SPEEDUP_FLOOR,
            "model_floor": MODEL_SPEEDUP_FLOOR,
            "chains": rows, "model_layer": model}
@@ -183,13 +193,15 @@ def main(argv=None) -> None:
 
     problems = []
     for row in rows:
-        if not row["bit_parity"]:
-            problems.append(f"{row['shape']}: not bit-identical to the "
-                            f"explicit-schedule oracle "
-                            f"(max err {row['max_err']:.3e})")
-        if not row["bit_parity_sequential"]:
-            problems.append(f"{row['shape']}: merged kernel not "
-                            f"bit-identical to sequential dispatch")
+        if row["oracle_rel_err"] > PARITY_RTOL:
+            problems.append(f"{row['shape']}: off the explicit-schedule "
+                            f"oracle by {row['oracle_rel_err']:.3e} "
+                            f"(relative) > {PARITY_RTOL}")
+        if row["sequential_rel_err"] > PARITY_RTOL:
+            problems.append(f"{row['shape']}: merged kernel off "
+                            f"sequential dispatch by "
+                            f"{row['sequential_rel_err']:.3e} > "
+                            f"{PARITY_RTOL}")
         if not row["merged_groups"]:
             problems.append(f"{row['shape']}: no merged group lowered")
         if row["hbm_ratio"] < HBM_RATIO_FLOOR:
@@ -200,13 +212,15 @@ def main(argv=None) -> None:
             problems.append(f"{row['shape']}: measured_speedup "
                             f"{row['measured_speedup']:.2f} < floor "
                             f"{MEASURED_SPEEDUP_FLOOR}")
-    if not model["bit_parity"]:
-        problems.append(f"model_layer {model['shape']}: not bit-identical"
-                        f" to models.transformer.dense_layer_forward "
-                        f"(max err {model['max_err']:.3e})")
-    if not model["bit_parity_sequential"]:
+    if model["oracle_rel_err"] > PARITY_RTOL:
+        problems.append(f"model_layer {model['shape']}: off "
+                        f"models.transformer.dense_layer_forward by "
+                        f"{model['oracle_rel_err']:.3e} > {PARITY_RTOL}")
+    if model["sequential_rel_err"] > PARITY_RTOL:
         problems.append(f"model_layer {model['shape']}: merged kernel "
-                        f"not bit-identical to sequential dispatch")
+                        f"off sequential dispatch by "
+                        f"{model['sequential_rel_err']:.3e} > "
+                        f"{PARITY_RTOL}")
     if not model["merged_groups"]:
         problems.append(f"model_layer {model['shape']}: no merged group "
                         f"lowered (whole-layer fusion regressed)")
@@ -223,8 +237,11 @@ def main(argv=None) -> None:
     print("graph_fusion gates passed "
           f"(hbm_ratio floor {HBM_RATIO_FLOOR}, measured_speedup floor "
           f"{MEASURED_SPEEDUP_FLOOR}, model_layer floor "
-          f"{MODEL_SPEEDUP_FLOOR}, bit parity)")
+          f"{MODEL_SPEEDUP_FLOOR}, parity rtol {PARITY_RTOL})")
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

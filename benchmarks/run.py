@@ -13,7 +13,7 @@ Sections:
   serve  — continuous-batching vs static-batch serving load (open-loop,
          mixed lengths; parity + speedup gate, BENCH_serve.json emission)
   graph  — fused vs unfused attention+MLP chain (HBM-bytes proxy floor +
-         bit parity vs the explicit-schedule oracle, BENCH_graph.json)
+         fp32 parity vs the explicit-schedule oracle, BENCH_graph.json)
   table3 — MM throughput comparison (XLA baselines + TPU roofline projection)
   roofline — aggregated dry-run roofline table (if results/dryrun exists)
 
@@ -44,6 +44,9 @@ def main(argv=None) -> None:
                          "smoke variants; the flag is the bench-regress "
                          "contract and gates the graph section's size)")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     t0 = time.time()
     failures = []
 

@@ -11,6 +11,9 @@ back the compiled winner — DSE to executable in two calls.
 import repro
 from repro.core import algebra, dse, plan, stt
 from repro.dist.schedules import schedule_from_comm_plan
+from repro.launch.cache import enable_compile_cache
+
+enable_compile_cache()
 
 
 g = algebra.gemm(512, 512, 512)

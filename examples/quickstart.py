@@ -21,6 +21,22 @@ import numpy as np
 
 import repro
 from repro.core import algebra
+from repro.launch.cache import enable_compile_cache
+
+enable_compile_cache()
+#: Pallas kernels compile with Mosaic on a TPU and run in the Pallas
+#: interpreter anywhere else (what ``repro.generate`` picks by default)
+INTERPRET = jax.default_backend() != "tpu"
+#: max |error| / max |oracle| allowed between two fp32 paths: they sum
+#: the same products in different orders (about k * 2^-24 ~ 4e-6 of the
+#: output scale here); a bf16 stage would sit near 2^-8 ~ 4e-3
+RTOL = 1e-5
+
+
+def rel_err(got, want) -> float:
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
 
 # 1. the computation: C[m,n] += A[m,k] * B[n,k]
 gemm = algebra.gemm(m=256, n=256, k=256)
@@ -78,15 +94,16 @@ x = jnp.array(rng.standard_normal((64, 64)), jnp.float32)
 w1 = jnp.array(rng.standard_normal((64, 64)), jnp.float32)
 w2 = jnp.array(rng.standard_normal((32, 64)), jnp.float32)
 y = gacc({"x": x, "w1": w1, "w2": w2})
-# jit the oracle with the operands as *arguments* (a closed-over constant
-# would be folded at trace time on a different arithmetic path)
-want = jax.jit(lambda x, w1, w2:
-               jax.nn.gelu(x @ w1.T, approximate=True) @ w2.T)(x, w1, w2)
-err = float(jnp.abs(y - want).max())
+# the fp32 oracle; "highest" keeps XLA on a TPU from rounding fp32 matmul
+# inputs to bf16
+with jax.default_matmul_precision("highest"):
+    want = jax.jit(lambda x, w1, w2:
+                   jax.nn.gelu(x @ w1.T, approximate=True) @ w2.T)(x, w1, w2)
+err = rel_err(y, want)
 print(f"\nfused gemm-gelu-gemm: fused edges {grep.fused_edges}, "
       f"HBM bytes {grep.hbm_bytes:.0f} vs {grep.hbm_bytes_unfused:.0f} "
-      f"unfused ({grep.hbm_ratio:.2f}x), max err {err:.2e}")
-assert err == 0.0 and grep.hbm_ratio > 1.0
+      f"unfused ({grep.hbm_ratio:.2f}x), relative err {err:.2e}")
+assert err <= RTOL and grep.hbm_ratio > 1.0
 
 # the fused chain is not just an accounting story: the whole group runs
 # as ONE Pallas megakernel with the intermediate in VMEM scratch.
@@ -96,9 +113,9 @@ from repro.graph import executor as graph_executor
 from repro.tune.measure import measure
 
 assert gacc.group_kernels, "the gemm-gelu-gemm chain should merge"
-seq = graph_executor.build(graph, interpret=True, merge=False)
+seq = graph_executor.build(graph, interpret=INTERPRET, merge=False)
 ops = {"x": x, "w1": w1, "w2": w2}
-assert bool(jnp.all(gacc(ops) == seq(ops)))     # bit-exact either way
+assert rel_err(gacc(ops), seq(ops)) <= RTOL
 t_merged = measure(gacc, ops, warmup=1, repeats=5).median_s
 t_seq = measure(seq, ops, warmup=1, repeats=5).median_s
 print(f"merged megakernel {list(gacc.group_kernels)}: "
@@ -119,9 +136,10 @@ lacc = repro.generate(layer)
 lrep = lacc.cost_report()
 lops = layer.random_operands(seed=0)
 lout = lacc(lops)
-assert bool(jnp.all(lout == from_model.layer_oracle(lops)))  # bit parity
-lseq = graph_executor.build(layer, interpret=True, merge=False)
-assert bool(jnp.all(lout == lseq(lops)))
+with jax.default_matmul_precision("highest"):
+    assert rel_err(lout, from_model.layer_oracle(lops)) <= RTOL
+lseq = graph_executor.build(layer, interpret=INTERPRET, merge=False)
+assert rel_err(lout, lseq(lops)) <= RTOL
 t_layer = measure(lacc, lops, warmup=1, repeats=5).median_s
 t_layer_seq = measure(lseq, lops, warmup=1, repeats=5).median_s
 print(f"\ntransformer layer graph: merged {list(lacc.group_kernels)}, "

@@ -24,6 +24,9 @@ def main() -> None:
     ap.add_argument("--max-context", type=int, default=64)
     ap.add_argument("--page-size", type=int, default=8)
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     print(f"serving {cfg.name} ({cfg.family}); "

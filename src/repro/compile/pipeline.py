@@ -13,9 +13,10 @@ into a runnable, validated kernel:
        template-reuse claim, in code, executing exactly the algebra's
        MACs),
     3. the *shared*, batch-aware tile chooser (``core.tiling`` — the same
-       one the cost model prices with) maps the STT tile onto Pallas block
-       sizes via ``tiling.form_blocks``, replacing the historic
-       hard-coded 128s,
+       one the cost model prices with) maps the STT tile onto GEMM block
+       sizes via ``tiling.form_blocks``, and ``stt_gemm.legal_blocks``
+       rounds each up to the least block Mosaic accepts (lane/sublane
+       multiples or the full extent),
     4. the result is cached on (algebra, dataflow, shapes, dtype,
        interpret, backend, array config) so serving / benchmark paths
        never re-trace, and
@@ -43,6 +44,7 @@ from ..core.tiling import ArrayConfig
 from ..kernels import epilogue as epilogue_mod
 from ..kernels import fused_chain as fused_chain_mod
 from ..kernels import ops
+from ..kernels import stt_gemm
 from .lowering import LoweredForm, lower_form
 
 #: auto-validate at lower time below this many MACs (a pure-python oracle
@@ -63,7 +65,7 @@ class CompiledKernel:
     dataflow: Dataflow
     plan: plan_mod.ExecutionPlan
     form: LoweredForm
-    blocks: Tuple[int, int, int]        # (bm, bn, bk) from the STT tile
+    blocks: Tuple[int, int, int]        # chip-legal (bm, bn, bk)
     stationary: str                     # GEMM operand pinned in VMEM
     cfg: ArrayConfig
     dtype: jnp.dtype
@@ -318,7 +320,9 @@ def _blocks_from_tile(alg: TensorAlgebra, df: Dataflow, form: LoweredForm,
                       cfg: ArrayConfig) -> Tuple[int, int, int]:
     """Map the STT tile (per selected loop) onto GEMM block sizes via the
     shared, batch-aware chooser (``core.tiling.form_blocks``): loops
-    folded onto the batch grid dims never inflate a block."""
+    folded onto the batch grid dims never inflate a block.  ``lower``
+    then rounds them up to blocks the chip's compiler accepts
+    (``stt_gemm.legal_blocks``); the cost model keeps pricing the tile."""
     return tiling.form_blocks(alg, df, form, cfg.pe_dims)
 
 
@@ -433,13 +437,22 @@ def lower(alg: TensorAlgebra, df: Optional[Dataflow] = None, *,
         reason = _epilogue_legal_for_form(alg, form, epilogue)
         if reason is not None:
             raise ValueError(reason)
-    if blocks is None:
-        blocks = _blocks_from_tile(alg, df, form, cfg)
-    if epilogue_mod.has_softmax(epilogue) and blocks[1] != form.n:
-        # a row softmax needs the whole unpadded row in one block
-        blocks = (blocks[0], form.n, blocks[2])
     stationary = ("A" if ep.kernel.resident_tensor in form.lhs_tensors
         else "B")
+    if blocks is None:
+        blocks = _blocks_from_tile(alg, df, form, cfg)
+    softmax = epilogue_mod.has_softmax(epilogue)
+    if softmax:
+        # a row softmax needs the whole unpadded row in one block
+        blocks = (blocks[0], form.n, blocks[2])
+    template = ep.kernel.template
+    if epilogue and template == "operand_stationary" and stationary == "A":
+        template = "output_stationary"      # ops.stt_matmul reroutes it
+    if form.sparse is None:
+        # blocks Mosaic accepts, within the scoped VMEM limit
+        blocks = stt_gemm.fit_blocks(
+            template, (form.m, form.n, form.k), blocks, dtype,
+            cfg.vmem_budget_bytes, stationary=stationary, keep_n=softmax)
     kernel = CompiledKernel(
         algebra=alg, dataflow=df, plan=ep, form=form, blocks=blocks,
         stationary=stationary, cfg=cfg, dtype=jnp.dtype(dtype),

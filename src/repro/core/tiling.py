@@ -28,8 +28,10 @@ class ArrayConfig:
     freq_mhz: float = 320.0
     onchip_gbps: float = 32.0
     elem_bytes: int = 2            # INT16 for the DSE experiments
-    #: per-core VMEM available to kernel scratch (TPU ~16 MB/core); caps the
-    #: operand-stationary strip accumulator, see kernels/stt_gemm.py.
+    #: VMEM one kernel may use: Mosaic's default scoped limit on TPU v5e
+    #: (16 MiB of the core's 128 MiB, ``core.tpu.V5E``).  Generated blocks
+    #: and fused groups are fitted to it (kernels/stt_gemm.fit_blocks,
+    #: graph/planner.py).
     vmem_budget_bytes: int = 16 * 1024 * 1024
 
     @property

@@ -1,12 +1,14 @@
-"""Target-hardware model: TPU v5e constants and roofline terms.
+"""Target-hardware model: TPU chip peaks and roofline terms.
 
-The container is CPU-only; TPU v5e is the *target*.  All roofline numbers in
-EXPERIMENTS.md are derived from compiled-HLO statistics with these constants
-(per the assignment):
+Peaks are keyed by the ``device_kind`` JAX reports for the chip
+(``jax.devices()[0].device_kind``); a kind missing from :data:`PEAKS` is
+an error, never a default.  TPU v5e reports ``"TPU v5 lite"``.  Its
+numbers are from Google Cloud's "TPU v5e" documentation page:
 
     peak bf16 compute : 197 TFLOP/s per chip
-    HBM bandwidth     : 819 GB/s per chip
-    ICI link bandwidth: ~50 GB/s per link
+    peak int8 compute : 394 TOP/s per chip
+    HBM               : 16 GB at 819 GB/s per chip
+    interchip (ICI)   : 1,600 Gbit/s per chip = 50 GB/s on each of 4 links
 """
 from __future__ import annotations
 
@@ -16,16 +18,38 @@ from typing import Dict
 
 @dataclasses.dataclass(frozen=True)
 class TpuSpec:
-    name: str = "tpu-v5e"
-    peak_flops_bf16: float = 197e12        # FLOP/s per chip
-    hbm_bw: float = 819e9                  # bytes/s per chip
-    ici_bw_per_link: float = 50e9          # bytes/s per link
-    hbm_bytes: float = 16e9                # HBM capacity per chip
-    vmem_bytes: float = 128 * 2 ** 20      # ~128 MiB VMEM per core
-    mxu_dim: int = 128                     # systolic array tile
+    name: str
+    peak_flops_bf16: float                 # FLOP/s per chip
+    peak_ops_int8: float                   # OP/s per chip
+    hbm_bw: float                          # bytes/s per chip
+    ici_bw_per_link: float                 # bytes/s per link
+    hbm_bytes: float                       # HBM capacity per chip
+    vmem_bytes: float                      # physical VMEM per core
+    #: what Mosaic lets one kernel use unless its CompilerParams raise it
+    vmem_scoped_limit_bytes: float
+    mxu_dim: int                           # systolic array tile
 
 
-V5E = TpuSpec()
+#: device_kind -> peaks (Google Cloud "TPU v5e" page)
+PEAKS: Dict[str, TpuSpec] = {
+    "TPU v5 lite": TpuSpec(
+        name="tpu-v5e", peak_flops_bf16=197e12, peak_ops_int8=394e12,
+        hbm_bw=819e9, ici_bw_per_link=1600e9 / 8 / 4, hbm_bytes=16e9,
+        vmem_bytes=128 * 2 ** 20, vmem_scoped_limit_bytes=16 * 2 ** 20,
+        mxu_dim=128),
+}
+
+V5E = PEAKS["TPU v5 lite"]
+
+
+def spec_for(device_kind: str) -> TpuSpec:
+    """The peaks of a chip by its JAX ``device_kind``; raises for a chip
+    the table does not hold."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks recorded for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass

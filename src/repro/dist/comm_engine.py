@@ -46,7 +46,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import jax_compat
 from ..core import plan as plan_mod
 from ..core.plan import CommPlan, PartitionSolution, TensorPartition
 
@@ -455,7 +454,7 @@ def _dense_out_stationary_fn(sol, form, mesh, dtype, in_specs, out_spec,
         if double_ring:
             lhs = _skew(lhs, s0, roll_axis=-1, block_axis=-2)
             rhs = _skew(rhs, s1, roll_axis=-2, block_axis=-1)
-        out = jax_compat.shard_map(
+        out = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
             check_vma=False)(lhs, rhs)
         out = out[..., :m, :n]
@@ -540,7 +539,7 @@ def _compressed_out_stationary_fn(sol, form, mesh, dtype, comp, out_spec,
             dn2d = _pad_dim(_pad_dim(dn2d, -2, f_m), -1, comp.d0_pad)
             dn2d = dn2d[:, :comp.d0_pad]
             args = (pay, sc, kc, dn2d)
-        out = jax_compat.shard_map(
+        out = jax.shard_map(
             body, mesh=mesh, in_specs=(*triple_specs, dense_spec),
             out_specs=out_spec, check_vma=False)(*args)
         return out[..., :m, :n]
@@ -634,7 +633,7 @@ def _dense_k_spatial_fn(sol, form, mesh, dtype, in_specs, out_spec, kmult,
                 lhs = _pad_dim(lhs, -3, f_b)
             if form.rhs_batched:
                 rhs = _pad_dim(rhs, -3, f_b)
-        out = jax_compat.shard_map(
+        out = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
             check_vma=False)(lhs, rhs)
         out = out[..., :m, :n]
@@ -683,7 +682,7 @@ def _compressed_k_spatial_fn(sol, form, mesh, dtype, comp, out_spec,
             dn2d = _pad_dim(_pad_dim(dn2d, -2, max(f_m, m_mult)),
                             -1, comp.d0_pad)
             dn2d = dn2d[:, :comp.d0_pad]
-        out = jax_compat.shard_map(
+        out = jax.shard_map(
             body, mesh=mesh, in_specs=(*triple_specs, dense_spec),
             out_specs=out_spec, check_vma=False)(pay, sc, kc, dn2d)
         return out[..., :m, :n]

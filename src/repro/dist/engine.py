@@ -21,8 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import jax_compat
-
 
 def square_submesh(n: int = 2) -> Mesh:
     """An (n, n) mesh over the first n*n devices (Cannon needs square)."""
@@ -44,7 +42,7 @@ def summa_matmul(a: jax.Array, b: jax.Array, mesh: Mesh) -> jax.Array:
         return jnp.dot(a_row, b_col, preferred_element_type=jnp.float32
                        ).astype(a_blk.dtype)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(P("x", "y"), P("x", "y")),
         out_specs=P("x", "y"))(a, b)
 
@@ -60,7 +58,7 @@ def ring_reduce_matmul(a: jax.Array, b: jax.Array, mesh: Mesh) -> jax.Array:
         partial = jnp.dot(a_blk, b_blk, preferred_element_type=jnp.float32)
         return jax.lax.psum(partial, ("x", "y")).astype(a_blk.dtype)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(P(None, ("x", "y")), P(("x", "y"), None)),
         out_specs=P(None, None))(a, b)
 
@@ -102,6 +100,6 @@ def cannon_matmul(a: jax.Array, b: jax.Array, mesh: Mesh) -> jax.Array:
         _, _, acc = jax.lax.fori_loop(0, s, step, (a_blk, b_blk, acc))
         return acc.astype(a_blk.dtype)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(P("x", "y"), P("x", "y")),
         out_specs=P("x", "y"), check_vma=False)(a, b)

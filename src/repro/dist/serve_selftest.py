@@ -10,7 +10,8 @@ Checks, on 8 fake devices:
   * ``place_pools`` shards every page pool over that axis (page axis
     padded to the axis size, scratch page preserved);
   * continuous decode over the SHARDED pools stays bit-identical to the
-    unsharded slot engine, insert/evict churn included.
+    unsharded slot engine, insert/evict churn included, and compiles
+    the decode step once.
 """
 import os
 
@@ -75,19 +76,16 @@ def main() -> None:
     print(f"sharded continuous decode bit-matches unsharded "
           f"({len(got)} steps)")
 
-    # the no-recompile contract under sharding: jit legitimately re-keys
-    # while pool shardings settle on the first steps, but once steady,
-    # insert/evict churn must not add entries — and results must repeat.
+    # the no-recompile contract under sharding: the placed pools and the
+    # device twin carry the shardings the step returns, so insert/evict
+    # churn never adds an entry — and results must repeat.
     for slot in eng.live_slots():
         eng.evict(slot)
-    steady = eng.decode_compiles
     got2 = _drive(eng, prompts)
     for g, w in zip(got2, want):
         np.testing.assert_array_equal(g, w)
-    assert eng.decode_compiles == steady, (
-        (steady, eng.decode_compiles))
-    print(f"insert/evict churn on the sharded engine: compiles stable "
-          f"at {steady}")
+    assert eng.decode_compiles == 1, eng.decode_compiles
+    print("insert/evict churn on the sharded engine: one decode compile")
     print("serve placement selftest OK")
 
 

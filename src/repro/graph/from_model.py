@@ -84,7 +84,7 @@ def layer_graph_from_config(cfg: ModelConfig,
 
 def layer_oracle(operands: Dict[str, "object"], dtype: str = "float32"):
     """Run :func:`repro.models.transformer.dense_layer_forward` on a
-    graph-operand dict (edge name -> array), for bit-parity checks."""
+    graph-operand dict (edge name -> array), the fp32 parity oracle."""
     from ..models.transformer import dense_layer_forward
 
     return dense_layer_forward(*(operands[e] for e in LAYER_INPUTS),

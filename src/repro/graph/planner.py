@@ -407,32 +407,43 @@ def _partition_agrees(p: NodePlan, c_df: Dataflow, c_form: LoweredForm,
 def _agree_blocks(plans: Dict[str, NodePlan], fused: List[EdgeDecision],
                   graph: AlgebraGraph, cfg: ArrayConfig) -> None:
     """Make producer output blocks match consumer lhs blocks on every
-    fused edge (fixpoint: agreement on one edge can narrow another)."""
+    fused edge.
+
+    Two passes.  First, an edge whose intermediate fits the residency
+    limit gets whole-tensor blocks on both sides (and the consumer's
+    accumulator too when that fits: the single-dot schedule).  Then a
+    gcd fixpoint narrows every fused edge's blocks to common divisors.
+    The fixpoint only ever lowers a block, so it terminates; the
+    whole-tensor widening is not repeated inside it, because a node
+    shared by a resident and a narrowed edge would otherwise flip
+    between the two forever."""
     limit = _vmem_resident_limit(cfg)
-    for _ in range(1 + len(fused)):
+
+    def assign(node: NodePlan, blocks: Tuple[int, int, int]) -> bool:
+        if blocks == node.blocks:
+            return False
+        node.blocks, node.blocks_constrained = blocks, True
+        return True
+
+    for e in fused:
+        p, c = plans[e.producer], plans[e.consumer]
+        m_e, n_e = graph.edge_shape(e.edge)
+        if 4 * m_e * n_e <= limit:
+            # whole tensor: one resident block; the consumer accumulator
+            # spans the row when it fits too (one jnp.dot per node)
+            bn_c = c.form.n if 4 * m_e * c.form.n <= limit else c.blocks[1]
+            assign(p, (m_e, n_e, p.blocks[2]))
+            assign(c, (m_e, bn_c, n_e))
+    changed = True
+    while changed:
         changed = False
         for e in fused:
             p, c = plans[e.producer], plans[e.consumer]
             m_e, n_e = graph.edge_shape(e.edge)
-            bn_c = c.blocks[1]
-            if 4 * m_e * n_e <= limit:
-                bm, bn = m_e, n_e       # whole tensor: one resident block
-                if 4 * m_e * c.form.n <= limit:
-                    # consumer accumulator fits too: single-dot schedule
-                    # (bit-identical to the oracle's one jnp.dot)
-                    bn_c = c.form.n
-            else:
-                bm = math.gcd(math.gcd(p.blocks[0], c.blocks[0]), m_e)
-                bn = math.gcd(math.gcd(p.blocks[1], c.blocks[2]), n_e)
-            new_p = (bm, bn, p.blocks[2])
-            new_c = (bm, bn_c, bn)
-            if new_p != p.blocks:
-                p.blocks, p.blocks_constrained, changed = new_p, True, True
-            if new_c != c.blocks:
-                c.blocks, c.blocks_constrained, changed = new_c, True, True
-        if not changed:
-            return
-    raise RuntimeError("tile agreement did not converge")   # pragma: no cover
+            bm = math.gcd(math.gcd(p.blocks[0], c.blocks[0]), m_e)
+            bn = math.gcd(math.gcd(p.blocks[1], c.blocks[2]), n_e)
+            changed |= assign(p, (bm, bn, p.blocks[2]))
+            changed |= assign(c, (bm, c.blocks[1], bn))
 
 
 # ---------------------------------------------------------------------------
@@ -710,7 +721,13 @@ def _dag_group(names: List[str], plans: Dict[str, NodePlan],
         ext_bytes += nel * (4 if role in ("res", "bias") else eb)
     out_bytes = dag[-1].m * dag[-1].n * eb
     out_bytes += sum(st.m * st.n * eb for st in dag if st.tap >= 0)
-    vmem = ext_bytes + out_bytes + scratch
+    # the pipeline double-buffers every whole-tensor operand and output;
+    # the widest stage's fp32 dot result is live alongside the scratch;
+    # fp32 operands add their bf16 split parts (stt_gemm.vmem_bytes)
+    vmem = (2 * (ext_bytes + out_bytes) + scratch
+            + max(st.m * st.n * 4 for st in dag))
+    if eb == 4:
+        vmem += 2 * ext_bytes
     eligible, reason = True, ""
     if scratch > _vmem_resident_limit(cfg):
         eligible = False

@@ -26,8 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from . import pallas_compat as _compat
 
 #: static block-COO coordinate list: ((block_row, block_col), ...) sorted
 Coords = Tuple[Tuple[int, int], ...]
@@ -100,8 +100,6 @@ def bsr_matmul(sparse: jax.Array, dense: jax.Array, *, coords: Coords,
     the static, row-major-sorted block-COO list with (bm, bk) blocks;
     n is padded to a ``bn`` multiple.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     (m, k), n = sparse.shape, dense.shape[1]
     if m % bm or k % bk:
         raise ValueError(f"sparse operand ({m},{k}) not tiled by blocks "
@@ -133,7 +131,7 @@ def bsr_matmul(sparse: jax.Array, dense: jax.Array, *, coords: Coords,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n + pad_n), out_dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(coord_arr, data, dense)

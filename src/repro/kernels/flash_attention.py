@@ -19,8 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from . import pallas_compat as _compat
 
 NEG_INF = float(-1e30)
 
@@ -78,7 +78,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Grid: (B, Hq, Lq/bq, Lkv/bkv) — kv innermost so the online-softmax
     statistics stay resident; q/k/v blocks stream through the pipeline.
     """
-    from jax.experimental.pallas import tpu as pltpu
     b, hq, lq, d = q.shape
     _, hkv, lkv, _ = k.shape
     if hq % hkv:
@@ -113,7 +112,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 128), jnp.float32),   # running denom l
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

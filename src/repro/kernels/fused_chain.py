@@ -50,10 +50,10 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import epilogue as _ep
-from . import pallas_compat as _compat
-from .stt_gemm import _flush_block
+from .stt_gemm import _flush_block, mxu_dot
 
 #: valid stage interleave orders (the merged-kernel tuner knob)
 FUSED_INTERLEAVES = ("chain", "stage")
@@ -120,15 +120,24 @@ def stage_scratch_bytes(stages: Sequence[ChainStage], m: int,
 def chain_vmem_bytes(stages: Sequence[ChainStage], m: int, k0: int,
                      bm: int, itemsize: int,
                      interleave: str = "chain") -> int:
-    """Total VMEM residency estimate of the merged kernel: lhs block +
-    all pinned rhs (and bias rows, fp32) + output block + intermediate
-    scratch.  The planner compares this against the array config's
-    ``vmem_budget_bytes`` before committing to a merged lowering."""
+    """Total VMEM residency estimate of the merged kernel: the Pallas
+    pipeline double-buffers every input and output block (lhs block, all
+    pinned rhs, fp32 bias rows, output block), plus the fp32 result of
+    the widest stage's dot and the intermediate scratch; fp32 operands
+    add their bf16 split parts (see ``stt_gemm.vmem_bytes``).  The
+    planner compares this against the array config's
+    ``vmem_budget_bytes`` (the chip's scoped VMEM limit) before
+    committing to a merged lowering."""
     stages = tuple(stages)
-    resident = bm * k0 * itemsize                     # lhs block
-    resident += sum(st.k * st.n * itemsize for st in stages)   # weights
+    operands = bm * k0 * itemsize                     # lhs block
+    operands += sum(st.k * st.n * itemsize for st in stages)   # weights
+    resident = operands
     resident += sum(4 * st.n for st in stages if st.has_bias)  # bias rows
     resident += bm * stages[-1].n * itemsize          # output block
+    resident *= 2                                     # double-buffered
+    resident += max(bm * st.n * 4 for st in stages)   # fp32 dot result
+    if itemsize == 4:
+        resident += 2 * operands                      # bf16 split parts
     if interleave == "stage":
         resident += stage_scratch_bytes(stages, m, itemsize)
     else:
@@ -170,8 +179,7 @@ def _chain_kernel(*refs, stages: Tuple[ChainStage, ...], n_bias: int,
     biases = _stage_bias_refs(stages, bias_refs)
     x = lhs_ref[...]
     for j, st in enumerate(stages):
-        acc = jnp.dot(x, rhs_refs[j][...],
-                      preferred_element_type=jnp.float32)
+        acc = mxu_dot(x, rhs_refs[j][...])
         if j + 1 < len(stages):
             scr[j][...] = _flush_block(acc, biases[j], st.epilogue,
                                        mid_dtype)
@@ -195,8 +203,7 @@ def _stage_kernel(*refs, stages: Tuple[ChainStage, ...], n_bias: int,
         @pl.when(s == j)
         def _run(j=j, st=st):
             x = lhs_ref[...] if j == 0 else scr[j - 1][row, :]
-            acc = jnp.dot(x, rhs_refs[j][...],
-                          preferred_element_type=jnp.float32)
+            acc = mxu_dot(x, rhs_refs[j][...])
             if j + 1 < len(stages):
                 scr[j][row, :] = _flush_block(acc, biases[j], st.epilogue,
                                               mid_dtype)
@@ -216,8 +223,6 @@ def _stage_kernel(*refs, stages: Tuple[ChainStage, ...], n_bias: int,
 def _fused_chain(lhs, *operands, stages: Tuple[ChainStage, ...],
                  bm: int, interleave: str, out_dtype: str,
                  interpret: bool):
-    from jax.experimental.pallas import tpu as pltpu
-
     n_stage = len(stages)
     n_bias = sum(1 for st in stages if st.has_bias)
     rhss = operands[:n_stage]
@@ -264,7 +269,7 @@ def _fused_chain(lhs, *operands, stages: Tuple[ChainStage, ...],
         out_specs=pl.BlockSpec((bm, n_last), imap_m),
         out_shape=jax.ShapeDtypeStruct((mp, n_last), jnp.dtype(out_dtype)),
         scratch_shapes=scratch,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
         interpret=interpret,
     )(lhs, *rhss, *bias_rows)
@@ -461,14 +466,12 @@ def _dag_kernel(*refs, stages: Tuple[DagStage, ...], n_ext: int,
             if st.kind == "batched":
                 a3 = _dag_fetch(ext, scr, st.lhs)       # (m, k, n)
                 v = _dag_fetch(ext, scr, st.rhs)        # (m, k)
-                acc = jax.lax.dot_general(
-                    v, a3, (((1,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)
+                acc = mxu_dot(v, a3, (((1,), (1,)), ((0,), (0,))))
             else:
                 x = _dag_fetch(ext, scr, st.lhs)
                 r = _dag_fetch(ext, scr, st.rhs,
                                transpose=st.rhs[0] == "scr")
-                acc = jnp.dot(x, r, preferred_element_type=jnp.float32)
+                acc = mxu_dot(x, r)
             b_ref = ext[st.bias] if st.has_bias else None
             y = _flush_block(acc, b_ref, st.epilogue, dtype)
             if st.res is not None:
@@ -490,8 +493,6 @@ def _dag_kernel(*refs, stages: Tuple[DagStage, ...], n_ext: int,
     jax.jit, static_argnames=("stages", "out_dtype", "interpret"))
 def _fused_dag(*exts, stages: Tuple[DagStage, ...], out_dtype: str,
                interpret: bool):
-    from jax.experimental.pallas import tpu as pltpu
-
     dt = jnp.dtype(out_dtype)
     last = stages[-1]
     n_tap = sum(1 for st in stages if st.tap >= 0)
@@ -516,7 +517,7 @@ def _fused_dag(*exts, stages: Tuple[DagStage, ...], out_dtype: str,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*exts)

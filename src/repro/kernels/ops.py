@@ -78,9 +78,10 @@ def stt_matmul(a: jax.Array, b: jax.Array, *, template: str = "output_stationary
     executes exactly the algebra's MACs.  Per-slice m/n/k are padded to
     block multiples; the batch dim never needs padding (batch block = 1).
 
-    ``vmem_budget`` caps the operand-stationary strip accumulator, which
-    is allocated **per batch slice**: when the per-slice (m, bn) fp32
-    strip would not fit, the call falls back to the output-stationary
+    ``vmem_budget`` caps the operand-stationary kernel's VMEM, whose
+    strip accumulator is allocated **per batch slice**: when the
+    per-slice (m, bn) fp32 strip and its blocks would not fit
+    (``stt_gemm.vmem_bytes``), the call falls back to the output-stationary
     template (same math, block-local residency) instead of erroring — the
     compile pipeline relies on this safety net.
 
@@ -125,10 +126,10 @@ def stt_matmul(a: jax.Array, b: jax.Array, *, template: str = "output_stationary
     if template == "operand_stationary" and vmem_budget is not None:
         # the strip extent follows the *streamed-output* dimension of one
         # batch slice: M for stationary B, N for stationary A
-        # (transposition symmetry)
-        strip_len = ap.shape[-2] if stationary == "B" else bp.shape[-1]
-        strip_bn = bn if stationary == "B" else bm
-        if (_gemm.operand_stationary_strip_bytes(strip_len, strip_bn)
+        # (transposition symmetry, handled inside vmem_bytes)
+        dims = (ap.shape[-2], bp.shape[-1], ap.shape[-1])
+        if (_gemm.vmem_bytes(template, dims, (bm, bn, bk),
+                             a.dtype.itemsize, stationary=stationary)
                 > vmem_budget):
             template = "output_stationary"
     kw = dict(bm=bm, bn=bn, bk=bk, interpret=interpret,

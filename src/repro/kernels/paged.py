@@ -20,8 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from . import pallas_compat as _compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def paged_gather(pool: jax.Array, page_table: jax.Array) -> jax.Array:
@@ -47,7 +46,6 @@ def paged_gather_pallas(pool: jax.Array, page_table: jax.Array, *,
                         interpret: bool = False) -> jax.Array:
     """The Pallas twin of :func:`paged_gather`: grid (C, n), one page DMA
     per step, page table scalar-prefetched into the index maps."""
-    from jax.experimental.pallas import tpu as pltpu
 
     p, page, f = pool.shape
     c, n = page_table.shape
@@ -63,7 +61,7 @@ def paged_gather_pallas(pool: jax.Array, page_table: jax.Array, *,
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((c, n, page, f), pool.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), pool)

@@ -20,8 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from . import pallas_compat as _compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(da_ref, x_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: int):
@@ -65,7 +64,6 @@ def ssd_scan(xdt: jax.Array, da: jax.Array, b: jax.Array, c: jax.Array, *,
     xdt: (BH, L, P) with dt folded in;  da: (BH, L) log decays;
     b, c: (BH, L, N) per-head (already group-broadcast).  Returns y (BH, L, P).
     """
-    from jax.experimental.pallas import tpu as pltpu
     bh, l, p = xdt.shape
     n = b.shape[-1]
     if l % chunk:
@@ -84,7 +82,7 @@ def ssd_scan(xdt: jax.Array, da: jax.Array, b: jax.Array, c: jax.Array, *,
         out_specs=pl.BlockSpec((1, chunk, p), lambda i, ci: (i, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, l, p), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(da, xdt, b, c)

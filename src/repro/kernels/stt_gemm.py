@@ -29,8 +29,12 @@ return rank-2 outputs, so plain GEMM call sites are unchanged.
 
 All grids end with the revisited axis innermost, so the Mosaic pipeline
 double-buffers streamed operands (compute/DMA overlap).  Block shapes
-default to the MXU-aligned 128 and are validated in ``interpret=True``
-mode on CPU (tests sweep shapes, batches and dtypes).
+default to the MXU-aligned 128.  Mosaic accepts a block only when its
+last dim is a multiple of 128 lanes and its second-to-last a multiple of
+the dtype's sublane count, or when either equals the array extent;
+:func:`legal_blocks` maps any requested (bm, bn, bk) onto that rule, and
+``tests/test_chip_compile.py`` checks the templates against the chip's
+compiler.
 """
 from __future__ import annotations
 
@@ -40,15 +44,62 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import epilogue as _ep
-from . import pallas_compat as _compat
 
 
 DEFAULT_BLOCK = 128
-#: per-core VMEM available for kernel scratch (TPU ~16 MB/core); the
-#: operand-stationary strip accumulator must fit in it.
+#: Mosaic's default scoped VMEM limit on TPU v5e (16 MiB of the core's
+#: 128 MiB); no kernel here raises it, so every block and scratch buffer
+#: of one pallas_call, double-buffered inputs included, must fit in it.
 DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024
+#: lanes of a vreg: the last block dim is a multiple of this (or full)
+LANE = 128
+
+
+def sublanes(dtype) -> int:
+    """Rows of ``dtype`` in one (8, 128) x 32-bit vreg tile: the multiple
+    Mosaic requires of a block's second-to-last dim (8 for fp32, 16 for
+    bf16, 32 for int8)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _align(block: int, full: int, unit: int) -> int:
+    """Least multiple of ``unit`` >= ``block``; the full extent when that
+    is not smaller (a full-extent block is always legal)."""
+    b = -(-max(1, block) // unit) * unit
+    return full if b >= full else b
+
+
+def legal_blocks(blocks: Tuple[int, int, int], dims: Tuple[int, int, int],
+                 dtype, *, lane_m: bool = False) -> Tuple[int, int, int]:
+    """Map a requested ``(bm, bn, bk)`` onto the least blocks >= it that
+    Mosaic accepts for a GEMM of per-slice ``dims = (m, n, k)``.
+
+    ``n`` and ``k`` are the last dim of some operand block (B and C; A)
+    in every template, so they round up to lanes; ``m`` is a row dim and
+    rounds up to the dtype's sublanes.  ``lane_m`` is for the
+    input-stationary template, whose transposed realization makes ``m``
+    the last dim of B^T and C^T.  Padding in ``ops.stt_matmul`` covers
+    dims the blocks do not divide."""
+    (bm, bn, bk), (m, n, k) = blocks, dims
+    return (_align(bm, m, LANE if lane_m else sublanes(dtype)),
+            _align(bn, n, LANE), _align(bk, k, LANE))
+
+
+def mxu_dot(a: jax.Array, b: jax.Array, dims=None) -> jax.Array:
+    """The templates' contraction, accumulated in fp32.  fp32 operands
+    contract at fp32 precision: Mosaic's default would round them to
+    bf16 on the MXU, and a kernel asked for fp32 must not compute in
+    bf16.  Narrower operands keep the default single pass.  ``dims`` are
+    ``lax.dot_general`` dimension numbers (None = a plain 2-D dot)."""
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else None)
+    if dims is None:
+        dims = (((a.ndim - 1,), (0,)), ((), ()))
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
 
 
 def _validate(m, n, k, bm, bn, bk):
@@ -123,12 +174,78 @@ def _flush_block(acc, bias_ref, epilogue: Tuple[str, ...], out_dtype):
     return acc.astype(out_dtype)
 
 
-def operand_stationary_strip_bytes(m: int, bn: int) -> int:
-    """VMEM footprint of the (m, bn) fp32 strip accumulator the
-    operand-stationary template allocates **per batch slice** (the batch
-    grid axis is outermost, so only one slice's strip is live at a time —
-    see matmul_operand_stationary)."""
-    return m * bn * 4
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) buffer: Mosaic lays it out in whole
+    (sublanes, 128) tiles, so a narrow dim still takes a full tile."""
+    sub = 8 * max(1, 4 // itemsize)
+    return -(-rows // sub) * sub * (-(-cols // LANE) * LANE) * itemsize
+
+
+def vmem_bytes(template: str, dims: Tuple[int, int, int],
+               blocks: Tuple[int, int, int], itemsize: int, *,
+               stationary: str = "B") -> int:
+    """VMEM one pallas_call of ``template`` holds **per batch slice**
+    (the batch grid axis is outermost, so slices reuse every buffer), in
+    whole tiles: double-buffered operand, output and bias blocks, the
+    template's accumulator — the (bm, bn) scratch of output-stationary or
+    the (m, bn) strip of operand-stationary, whose streamed-output extent
+    ``m`` is the padded per-slice one — and the fp32 values of one
+    accumulate step (the dot result, the accumulator read, their sum).
+    fp32 operands contract at full precision (:func:`mxu_dot`), for which
+    Mosaic splits both operand blocks into bf16 parts: up to as much
+    again as the double-buffered operand blocks.
+    The input-stationary realization (``stationary='A'``) swaps m and n.
+    Checked against the chip's compiler in tests/test_chip_compile.py."""
+    (m, n, k), (bm, bn, bk) = dims, blocks
+    if template == "operand_stationary" and stationary == "A":
+        m, n, bm, bn = n, m, bn, bm
+    if template in ("reduction_tree", "streaming"):
+        bk = k
+    acc = _tile_bytes(bm, bn, 4)
+    operands = _tile_bytes(bm, bk, itemsize) + _tile_bytes(bk, bn, itemsize)
+    total = 2 * (operands + _tile_bytes(bm, bn, itemsize)
+                 + _tile_bytes(1, bn, 4))
+    if itemsize == 4:
+        total += 2 * operands
+    if template == "output_stationary":
+        total += 4 * acc
+    elif template == "operand_stationary":
+        total += 3 * acc + _tile_bytes(-(-m // bm) * bm, bn, 4)
+    else:
+        total += 2 * acc
+    return total
+
+
+def fit_blocks(template: str, dims: Tuple[int, int, int],
+               blocks: Tuple[int, int, int], dtype, budget: int, *,
+               stationary: str = "B", keep_n: bool = False
+               ) -> Tuple[int, int, int]:
+    """Chip-legal blocks >= ``blocks`` (:func:`legal_blocks`), then
+    halved — largest first, staying legal — until :func:`vmem_bytes`
+    fits ``budget``.  An operand-stationary strip that cannot fit even at
+    the smallest blocks is fitted for the output-stationary template,
+    which ``ops.stt_matmul`` falls back to.  ``keep_n`` pins bn (a row
+    softmax needs the whole row in one block)."""
+    lane_m = template == "operand_stationary" and stationary == "A"
+    units = (LANE if lane_m else sublanes(dtype), LANE, LANE)
+    b = list(legal_blocks(blocks, dims, dtype, lane_m=lane_m))
+    free = [i for i in range(3)
+            if not (i == 1 and keep_n)
+            and not (i == 2 and template in ("reduction_tree",
+                                             "streaming"))]
+    item = jnp.dtype(dtype).itemsize
+    while vmem_bytes(template, dims, tuple(b), item,
+                     stationary=stationary) > budget:
+        halves = [(b[i], i) for i in free
+                  if _align(b[i] // 2, dims[i], units[i]) < b[i]]
+        if not halves:
+            if template == "operand_stationary":
+                return fit_blocks("output_stationary", dims, tuple(b),
+                                  dtype, budget, keep_n=keep_n)
+            break
+        _, i = max(halves)
+        b[i] = _align(b[i] // 2, dims[i], units[i])
+    return tuple(b)
 
 
 # ---------------------------------------------------------------------------
@@ -164,8 +281,7 @@ def _os_kernel_scratch(a_ref, b_ref, *rest, n_k: int, k_axis: int,
     @pl.when(pl.program_id(k_axis) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-    acc_ref[...] += jnp.dot(a_ref[0], b_ref[0],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(a_ref[0], b_ref[0])
     @pl.when(pl.program_id(k_axis) == n_k - 1)
     def _flush():
         o_ref[0] = _flush_block(acc_ref[...], bias_ref, epilogue, out_dtype)
@@ -178,8 +294,7 @@ def _os_kernel_inplace(a_ref, b_ref, *rest, n_k: int, k_axis: int,
     @pl.when(pl.program_id(k_axis) == 0)
     def _init():
         o_ref[0] = jnp.zeros_like(o_ref[0])
-    o_ref[0] += jnp.dot(a_ref[0], b_ref[0],
-                        preferred_element_type=jnp.float32).astype(out_dtype)
+    o_ref[0] += mxu_dot(a_ref[0], b_ref[0]).astype(out_dtype)
     if epilogue:
         # the accumulated block is final at the last k-step; the epilogue
         # reads it back at fp32 (the in-place strategy's usual precision
@@ -199,7 +314,6 @@ def matmul_output_stationary(a: jax.Array, b: jax.Array, *,
                              epilogue: Tuple[str, ...] = (),
                              bias: Optional[jax.Array] = None
                              ) -> jax.Array:
-    from jax.experimental.pallas import tpu as pltpu
     if grid_order == "default":
         grid_order = "mnk"
     elif grid_order in ("mn", "nm"):    # reduction-tree spelling: k innermost
@@ -254,7 +368,7 @@ def matmul_output_stationary(a: jax.Array, b: jax.Array, *,
             lambda bb, *ids: (bb, ids[ix["m"]], ids[ix["n"]])),
         out_shape=jax.ShapeDtypeStruct((nb, m, n), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
         interpret=interpret,
     )(*inputs)
@@ -279,8 +393,7 @@ def _ws_kernel(a_ref, b_ref, *rest, n_k: int, bm: int, out_dtype,
     @pl.when(kk == 0)
     def _init():
         acc_ref[sl, :] = jnp.zeros_like(acc_ref[sl, :])
-    acc_ref[sl, :] += jnp.dot(a_ref[0], b_ref[0],
-                              preferred_element_type=jnp.float32)
+    acc_ref[sl, :] += mxu_dot(a_ref[0], b_ref[0])
     @pl.when(kk == n_k - 1)
     def _flush():
         o_ref[0] = _flush_block(acc_ref[sl, :], bias_ref, epilogue,
@@ -304,11 +417,11 @@ def matmul_operand_stationary(a: jax.Array, b: jax.Array, *,
     The strip accumulator scratch is (m, bn) fp32 per batch slice — a VMEM
     residency that grows with the *full* per-slice M extent, not a block
     (the batch grid axis is outermost, so slices reuse one strip).
-    ``vmem_budget`` bounds it (pass None to skip the check);
+    ``vmem_budget`` bounds it with the blocks (:func:`vmem_bytes`; pass
+    None to skip the check);
     ``ops.stt_matmul`` auto-falls-back to the output-stationary template
     instead of tripping this error.
     """
-    from jax.experimental.pallas import tpu as pltpu
     if stationary == "A":
         if epilogue:
             # the transposition realization swaps the m/n axes, so a
@@ -330,13 +443,14 @@ def matmul_operand_stationary(a: jax.Array, b: jax.Array, *,
     (m, k), n = a3.shape[1:], b3.shape[2]
     _validate(m, n, k, bm, bn, bk)
     epilogue = _check_epilogue(epilogue, bias, n, bn)
-    strip = operand_stationary_strip_bytes(m, bn)
-    if vmem_budget is not None and strip > vmem_budget:
+    need = vmem_bytes("operand_stationary", (m, n, k), (bm, bn, bk),
+                      a3.dtype.itemsize)
+    if vmem_budget is not None and need > vmem_budget:
         raise ValueError(
-            f"operand-stationary strip accumulator needs {strip} bytes of "
-            f"VMEM per batch slice ((m={m}) x (bn={bn}) x 4B) but the "
-            f"budget is {vmem_budget}; shrink bn, tile m outside the "
-            f"kernel, or use the output_stationary template "
+            f"operand-stationary kernel needs {need} bytes of VMEM per "
+            f"batch slice (strip (m={m}) x (bn={bn}) x 4B plus blocks) "
+            f"but the budget is {vmem_budget}; shrink bn, tile m outside "
+            f"the kernel, or use the output_stationary template "
             f"(ops.stt_matmul falls back automatically)")
     out_dtype = out_dtype or a.dtype
     n_k = k // bk
@@ -360,7 +474,7 @@ def matmul_operand_stationary(a: jax.Array, b: jax.Array, *,
                                lambda bb, j, kk, i: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((nb, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -376,7 +490,7 @@ def _rt_kernel(a_ref, b_ref, *rest, out_dtype,
                epilogue: Tuple[str, ...] = ()):
     bias_ref = rest[0] if len(rest) == 2 else None
     o_ref = rest[-1]
-    acc = jnp.dot(a_ref[0], b_ref[0], preferred_element_type=jnp.float32)
+    acc = mxu_dot(a_ref[0], b_ref[0])
     o_ref[0] = _flush_block(acc, bias_ref, epilogue, out_dtype)
 
 
@@ -422,7 +536,7 @@ def matmul_reduction_tree(a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec(
             (1, bm, bn), lambda bb, *ids: (bb, ids[ix["m"]], ids[ix["n"]])),
         out_shape=jax.ShapeDtypeStruct((nb, m, n), out_dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(*inputs)

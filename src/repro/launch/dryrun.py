@@ -30,7 +30,6 @@ import traceback
 def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
     import jax
 
-    from .. import jax_compat
     from ..core import tpu
     from . import hlo_analysis, specs
     from .mesh import make_production_mesh
@@ -42,7 +41,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
     t0 = time.perf_counter()
     # jax 0.8: set_mesh (not the bare `with mesh:` resource env) is what
     # makes bare-PartitionSpec sharding constraints inside the model resolve
-    with jax_compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                          out_shardings=cell.out_shardings,
                          donate_argnums=cell.donate)

@@ -13,16 +13,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     Axes: ('data', 'model') single-pod; ('pod', 'data', 'model') multi-pod.
     DP runs over (pod, data); FSDP over data; TP/SP/EP over model.
     """
-    from .. import jax_compat
+    import jax
 
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax_compat.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (fake or real) devices exist — used by
     tests and the CPU examples."""
-    from .. import jax_compat
+    import jax
 
-    return jax_compat.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

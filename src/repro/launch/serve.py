@@ -20,6 +20,9 @@ def main() -> None:
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
+    from .cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
     import numpy as np

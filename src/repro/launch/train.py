@@ -26,6 +26,9 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config, no mesh (CPU CI)")
     args = ap.parse_args()
+    from .cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
 

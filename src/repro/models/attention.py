@@ -20,7 +20,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import jax_compat
 from ..configs.base import ModelConfig
 from . import common
 from .common import shard, stacked_dense_init
@@ -287,7 +286,7 @@ def apply_attention(p: Dict, x: jax.Array, cfg: ModelConfig, *,
         # model axis; otherwise over query rows (attention rows are
         # independent) — whisper's 12 heads don't divide a 16-way axis and
         # would otherwise replicate (B, H, Lq, Lkv) per device
-        mesh = jax_compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         model_size = dict(zip(mesh.axis_names, mesh.axis_sizes)
                           ).get("model", 1) if mesh.axis_names else 1
         heads_ok = cfg.n_heads % max(model_size, 1) == 0

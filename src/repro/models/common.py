@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import jax_compat
-
 
 # ---------------------------------------------------------------------------
 # Parameter leaves with logical axes
@@ -174,7 +172,7 @@ def shard(x: jax.Array, *axes) -> jax.Array:
 
     This keeps one set of constraints valid across the 1-device test mesh,
     the 16x16 pod and the 2x16x16 multi-pod mesh."""
-    mesh = jax_compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return x
     names = dict(zip(mesh.axis_names, mesh.axis_sizes))
@@ -204,4 +202,4 @@ def shard_pinned(x: jax.Array, *axes) -> jax.Array:
     y = shard(x, *axes)
     if y is x:
         return x
-    return jax_compat.optimization_barrier(y)
+    return jax.lax.optimization_barrier(y)

@@ -29,12 +29,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .. import jax_compat
-
-
 
 def _mesh_info():
-    mesh = jax_compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return None, (), 1
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
@@ -64,7 +61,7 @@ def gather_seq(x: jax.Array) -> Optional[jax.Array]:
     def body(xl):
         return lax.all_gather(xl, "model", axis=1, tiled=True)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=P(bspec, "model", None),
         out_specs=P(bspec, None, None),
@@ -88,7 +85,7 @@ def project_scatter(h: jax.Array, w: jax.Array) -> Optional[jax.Array]:
         return lax.psum_scatter(part, "model", scatter_dimension=1,
                                 tiled=True)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, "model"), P("model", None)),
         out_specs=P(bspec, "model", None),
@@ -122,7 +119,7 @@ def mlp_manual(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
         return lax.psum_scatter(part.astype(compute), "model",
                                 scatter_dimension=1, tiled=True)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, "model", None), P(None, "model"),
                   P(None, "model"), P("model", None)),
@@ -150,7 +147,7 @@ def qkv_manual(x: jax.Array, wq: jax.Array, wk: jax.Array, wv: jax.Array,
         return xf @ wql, xf @ wkl, xf @ wvl
 
     spec_out = P(bspec, None, "model")
-    return jax_compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, "model", None), P(None, "model"),
                   P(None, "model"), P(None, "model")),
@@ -223,7 +220,7 @@ def moe_manual(x: jax.Array, p: dict, cfg, compute
                                scatter_dimension=1, tiled=True)
         return out, aux
 
-    out, aux = jax_compat.shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, "model", None), P(None, None),
                   P(None, None, "model"), P(None, None, "model"),
@@ -260,7 +257,7 @@ def chunked_attn_manual(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return _chunked_attn(ql, kl, vl, causal=causal, window=window,
                              q_offset=off, bkv=bkv)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, "model", None),
                   P(bspec, None, None, None),

@@ -323,8 +323,11 @@ def place_pools(cache: PagedKVCache, mesh, spec) -> None:
     """Shard every page pool over the mesh with the solved spec (page
     axis split over the batch-carrying mesh axis).  Divisibility caveat:
     the pool keeps its scratch page, so the page axis is padded up to a
-    multiple of the axis size before placement."""
-    from jax.sharding import NamedSharding
+    multiple of the axis size before placement.  The pools are placed as
+    ``P(axis)``, the spelling the jitted decode step returns them in:
+    ``P(axis, None, None)`` shards them the same way but keys a second
+    compile of the step."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     axis = spec[0]
     n = (dict(zip(mesh.axis_names, mesh.devices.shape)).get(axis, 1)
@@ -334,4 +337,5 @@ def place_pools(cache: PagedKVCache, mesh, spec) -> None:
         pad = (-p) % max(n, 1)
         if pad:
             pool = jnp.pad(pool, ((0, pad), (0, 0), (0, 0)))
-        cache.pools[path] = jax.device_put(pool, NamedSharding(mesh, spec))
+        cache.pools[path] = jax.device_put(pool,
+                                           NamedSharding(mesh, P(axis)))

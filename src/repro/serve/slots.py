@@ -28,6 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..configs.base import ModelConfig
 from ..models import decode as dec
@@ -225,15 +226,28 @@ class SlotEngine:
         self._dev = None
 
     # -- one decode step ---------------------------------------------------
+    def _twin_put(self):
+        """Placement of the device twin: replicated over the mesh the page
+        pools were placed on (``pages.place_pools``), which is how the
+        step returns the tokens and positions it carries, else the default
+        device.  Either way every step sees one set of input shardings,
+        so the decode step compiles once on a mesh too."""
+        for pool in self.cache.pools.values():
+            sharding = pool.sharding
+            if isinstance(sharding, NamedSharding) and sharding.mesh.size > 1:
+                rep = NamedSharding(sharding.mesh, PartitionSpec())
+                return lambda x: jax.device_put(x, rep)
+        return jnp.asarray
+
     def step(self) -> ResultTokens:
         """Advance every live slot one token; packed device→host copy."""
         key = self._base_key
         if self.serve_cfg.temperature > 0.0:
             key = jax.random.fold_in(self._base_key, -1 - self._step_count)
         if self._dev is None:              # insert/evict since last step
-            self._dev = (jnp.asarray(self._tokens), jnp.asarray(self._pos),
-                         jnp.asarray(self._active),
-                         self.cache.device_table())
+            put = self._twin_put()
+            self._dev = (put(self._tokens), put(self._pos),
+                         put(self._active), put(self.cache.device_table()))
         tokens, pos, active, table = self._dev
         packed, (tokens, pos), pools, lanes = self._step_fn(
             self.params, tokens, pos, active, table,

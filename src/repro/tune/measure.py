@@ -25,19 +25,11 @@ import dataclasses
 import time
 from typing import Callable, Tuple
 
+import jax
+
 #: defaults shared by the tuner, the benchmarks and the CI smoke step
 DEFAULT_WARMUP = 1
 DEFAULT_REPEATS = 5
-
-
-def _block(x) -> None:
-    """Block until ``x`` (array or pytree of arrays) is ready."""
-    try:
-        import jax
-        jax.block_until_ready(x)
-    except (ImportError, AttributeError):
-        if hasattr(x, "block_until_ready"):
-            x.block_until_ready()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,11 +68,11 @@ def measure(fn: Callable, *args, warmup: int = DEFAULT_WARMUP,
         raise ValueError(f"repeats must be >= 1, got {repeats}")
     t0 = time.perf_counter()
     for _ in range(max(0, warmup)):
-        _block(fn(*args, **kwargs))
+        jax.block_until_ready(fn(*args, **kwargs))
     warmup_s = time.perf_counter() - t0
     times = []
     for _ in range(repeats):
         t0 = time.perf_counter()
-        _block(fn(*args, **kwargs))
+        jax.block_until_ready(fn(*args, **kwargs))
         times.append(time.perf_counter() - t0)
     return Measurement(times_s=tuple(times), warmup_s=warmup_s)

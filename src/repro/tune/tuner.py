@@ -106,28 +106,26 @@ def _t_rows(T: linalg.Mat) -> List[List[int]]:
     return [[int(v) for v in row] for row in T]
 
 
-def _clamp_blocks(blocks: Tuple[int, int, int], dims: Tuple[int, int, int]
-                  ) -> Tuple[int, int, int]:
-    return tuple(max(1, min(b, d)) for b, d in zip(blocks, dims))
-
-
 def block_candidates(analytical: Tuple[int, int, int],
-                     dims: Tuple[int, int, int]
+                     dims: Tuple[int, int, int], dtype=jnp.float32, *,
+                     lane_m: bool = False
                      ) -> List[Tuple[int, int, int]]:
     """Block-size candidates around the analytical pick: the pick itself
     (trial #0's variant), hardware-friendly clamps (128/256), the full
-    problem capped at 512 (fewest grid steps — the big interpret-mode
-    win), and the pick doubled.  Deduped, analytical first."""
+    problem capped at 512 (fewest grid steps), and the pick doubled.
+    Each goes through the same chip-legal mapping ``lower`` applies
+    (``stt_gemm.legal_blocks``) before dedup, so no trial is spent on a
+    block Mosaic refuses or on a twin of another.  Analytical first."""
     cands = [
         analytical,
-        _clamp_blocks((128, 128, 128), dims),
-        _clamp_blocks((256, 256, 256), dims),
-        _clamp_blocks((512, 512, 512), dims),
-        _clamp_blocks(tuple(b * 2 for b in analytical), dims),
+        (128, 128, 128),
+        (256, 256, 256),
+        (512, 512, 512),
+        tuple(b * 2 for b in analytical),
     ]
     out: List[Tuple[int, int, int]] = []
     for c in cands:
-        c = _clamp_blocks(c, dims)
+        c = _gemm.legal_blocks(c, dims, dtype, lane_m=lane_m)
         if c not in out:
             out.append(c)
     return out
@@ -232,7 +230,10 @@ def tune(alg: TensorAlgebra, dataflow: Optional[Dataflow] = None, *,
             break
         base = pipeline.lower(alg, df, validate=False, tuned=False, **lkw)
         dims = (base.form.m, base.form.n, base.form.k)
-        for blocks in block_candidates(base.blocks, dims):
+        lane_m = (base.template == "operand_stationary"
+                  and base.stationary == "A")
+        for blocks in block_candidates(base.blocks, dims, dtype,
+                                       lane_m=lane_m):
             for grid_order, accum in _knob_grid(base.template):
                 variant = Variant(blocks, grid_order, accum)
                 if df is untuned_df and variant == trials[0].variant:

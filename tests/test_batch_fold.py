@@ -222,16 +222,19 @@ def test_batched_operand_stationary_vmem_check_is_per_slice():
     from repro.kernels import stt_gemm
     a = jnp.zeros((8, 32, 16), jnp.float32)
     b = jnp.zeros((8, 16, 16), jnp.float32)
-    # budget exactly one (32, 16) fp32 strip: per-slice fits, batch x
-    # would not — must succeed
+    # budget exactly one slice's kernel ((32, 16) fp32 strip + blocks):
+    # per-slice fits, batch x would not — must succeed
+    one_slice = stt_gemm.vmem_bytes("operand_stationary", (32, 16, 16),
+                                    (16, 16, 16), 4)
+    assert one_slice < stt_gemm.vmem_bytes(
+        "operand_stationary", (8 * 32, 16, 16), (16, 16, 16), 4)
     out = stt_gemm.matmul_operand_stationary(
-        a, b, bm=16, bn=16, bk=16, interpret=True,
-        vmem_budget=32 * 16 * 4)
+        a, b, bm=16, bn=16, bk=16, interpret=True, vmem_budget=one_slice)
     assert out.shape == (8, 32, 16)
     with pytest.raises(ValueError, match="VMEM"):
         stt_gemm.matmul_operand_stationary(
             a, b, bm=16, bn=16, bk=16, interpret=True,
-            vmem_budget=32 * 16 * 4 - 1)
+            vmem_budget=one_slice - 1)
 
 
 # ---------------------------------------------------------------------------

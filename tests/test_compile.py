@@ -180,17 +180,27 @@ def test_lower_rejects_foreign_dataflow():
 # Tile chooser is shared between cost model and compiler
 # ---------------------------------------------------------------------------
 
-def test_blocks_come_from_shared_tile_chooser():
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_blocks_come_from_shared_tile_chooser(dtype):
     alg = algebra.gemm(256, 256, 256)
     df = stt.apply_stt(alg, alg.loops, stt.stt_from_name("output_stationary"))
-    kern = rcompile.lower(alg, df, interpret=True, validate=False)
-    tile, _, _ = tiling.choose_tile(alg, df, kern.cfg.pe_dims)
+    kern = rcompile.lower(alg, df, interpret=True, validate=False,
+                          dtype=dtype)
+    tile, _, util = tiling.choose_tile(alg, df, kern.cfg.pe_dims)
     per_loop = dict(zip(df.selected, tile))
-    assert kern.blocks == (per_loop["m"], per_loop["n"], per_loop["k"])
-    # and not the historic hard-coded 128 default
-    assert kern.blocks != (stt_gemm.DEFAULT_BLOCK,) * 3
-    # the cost model prices the same tile the compiler runs with
-    assert kern.cost_report().dataflow_name == df.name
+    # the blocks are the chip-legal mapping of the shared tile: m rounds
+    # up to the dtype's sublanes, n and k to 128 lanes (or the full dim)
+    sub = stt_gemm.sublanes(dtype)
+    up = lambda t, u: -(-t // u) * u
+    assert kern.blocks == (up(per_loop["m"], sub), up(per_loop["n"], 128),
+                           up(per_loop["k"], 128))
+    assert kern.blocks == stt_gemm.legal_blocks(
+        (per_loop["m"], per_loop["n"], per_loop["k"]), (256, 256, 256),
+        dtype)
+    # the cost model prices the STT tile itself, not the padded blocks
+    rep = kern.cost_report()
+    assert rep.dataflow_name == df.name
+    assert rep.utilization == pytest.approx(util)
 
 
 # ---------------------------------------------------------------------------
@@ -227,3 +237,50 @@ def test_stt_matmul_within_budget_unchanged():
                          bm=32, bn=32, bk=32, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(a) @ np.asarray(b),
                                rtol=1e-4, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# Chip-legal blocks (the rule Mosaic enforces; tests/test_chip_compile.py
+# checks it against the compiler itself)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype,sub", [(jnp.float32, 8), (jnp.bfloat16, 16)])
+def test_legal_blocks_round_up_to_tiles_or_full_extent(dtype, sub):
+    assert stt_gemm.sublanes(dtype) == sub
+    # a PE-array tile rounds up: m to sublanes, n and k to 128 lanes
+    assert stt_gemm.legal_blocks((16, 16, 16), (512, 6912, 2560),
+                                 dtype) == (16, 128, 128)
+    assert stt_gemm.legal_blocks((3, 200, 129), (512, 6912, 2560),
+                                 dtype) == (sub, 256, 256)
+    # a dim the rounded block would cover is taken whole
+    assert stt_gemm.legal_blocks((16, 16, 16), (1, 80, 9), dtype) == (1, 80, 9)
+    # the input-stationary realization puts m on the lane axis
+    assert stt_gemm.legal_blocks((16, 16, 16), (512, 512, 512), dtype,
+                                 lane_m=True)[0] == 128
+
+
+@pytest.mark.parametrize("template,stationary", [
+    ("output_stationary", "B"), ("operand_stationary", "B"),
+    ("operand_stationary", "A"), ("reduction_tree", "B")])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_fit_blocks_legal_and_within_budget(template, stationary, dtype):
+    dims = (512, 6912, 2560)
+    budget = stt_gemm.DEFAULT_VMEM_BUDGET
+    blocks = stt_gemm.fit_blocks(template, dims, (1024, 1024, 1024), dtype,
+                                 budget, stationary=stationary)
+    lane_m = template == "operand_stationary" and stationary == "A"
+    assert stt_gemm.legal_blocks(blocks, dims, dtype,
+                                 lane_m=lane_m) == blocks
+    assert stt_gemm.vmem_bytes(template, dims, blocks,
+                               jnp.dtype(dtype).itemsize,
+                               stationary=stationary) <= budget
+
+
+def test_fp32_blocks_count_the_precision_split():
+    # fp32 operands contract at full precision, which Mosaic runs on
+    # bf16 parts of both blocks: the estimate grows by twice the operand
+    # blocks (the compiler refused a 256x256x2560 fp32 block at 20.6 MiB)
+    dims, blocks = (512, 6912, 2560), (256, 256, 2560)
+    f32 = stt_gemm.vmem_bytes("reduction_tree", dims, blocks, 4)
+    bf16 = stt_gemm.vmem_bytes("reduction_tree", dims, blocks, 2)
+    assert f32 > 20 * 2 ** 20 > stt_gemm.DEFAULT_VMEM_BUDGET > bf16

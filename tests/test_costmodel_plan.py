@@ -224,3 +224,19 @@ class TestEnumerationFastPath:
         sel = [("c", "p", "q")]
         assert dse.enumerate_dataflows(cv, selections=sel) == {}
         assert dse.enumerate_dataflows_reference(cv, selections=sel) == {}
+
+
+def test_peaks_are_keyed_by_device_kind():
+    from repro.core import tpu
+
+    v5e = tpu.spec_for("TPU v5 lite")
+    assert v5e is tpu.V5E
+    assert v5e.peak_flops_bf16 == 197e12 and v5e.hbm_bw == 819e9
+    assert v5e.vmem_scoped_limit_bytes == 16 * 2 ** 20 < v5e.vmem_bytes
+
+
+def test_unknown_device_kind_raises():
+    from repro.core import tpu
+
+    with pytest.raises(KeyError, match="no peaks recorded"):
+        tpu.spec_for("cpu")

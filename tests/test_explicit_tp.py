@@ -21,11 +21,11 @@ from repro.models import attention
 from repro.train import trainer
 
 attention.FULL_SCORES_MAX_LEN = 16   # force the chunked/manual path
-from repro import jax_compat
-mesh = jax_compat.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 def grads_for(cfg, params, batch):
-    with jax_compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         return jax.jit(lambda p, b: jax.grad(
             lambda pp: trainer.loss_fn(pp, b, cfg)[0])(p))(params, batch)
 
@@ -41,7 +41,7 @@ outs = {}
 for flag in (False, True):
     cfg = dataclasses.replace(base, explicit_collectives=flag)
     params, _ = split(init_params(jax.random.PRNGKey(0), cfg))
-    with jax_compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         logits, _, _ = jax.jit(lambda p, t: forward(p, t, cfg))(params, toks)
     outs[flag] = (np.asarray(logits), flat(grads_for(cfg, params, batch)))
 lerr = np.abs(outs[True][0] - outs[False][0]).max()
@@ -59,7 +59,7 @@ outs = {}
 for flag in (False, True):
     cfg = dataclasses.replace(base, explicit_collectives=flag)
     params, _ = split(init_params(jax.random.PRNGKey(0), cfg))
-    with jax_compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         logits, aux, _ = jax.jit(lambda p, t: forward(p, t, cfg))(params, toks)
     outs[flag] = np.asarray(logits)
 lerr = np.abs(outs[True] - outs[False]).max()

@@ -30,6 +30,22 @@ from repro.models import chains
 from repro.tune import cache as tune_cache
 
 
+#: Relative tolerance (of max|oracle|) for comparing an fp32 execution
+#: path with another fp32 path or the fp64 oracle.  Merged, sequential
+#: and XLA paths sum the same products in different orders; each dot
+#: here has at most 64 terms, so their rounding stays near
+#: k * 2^-24 ~ 4e-6 of the output scale.  A bf16 stage anywhere on the
+#: path would sit near 2^-8 ~ 4e-3, 400x above this bound.
+FP32_RTOL = 1e-5
+
+
+def assert_fp32_close(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert got.shape == want.shape
+    err = float(np.abs(got - want).max())
+    assert err <= FP32_RTOL * float(np.abs(want).max()), err
+
+
 def small_gemm(m=16, n=16, k=16):
     return get_algebra("gemm", m=m, n=n, k=k)
 
@@ -343,15 +359,17 @@ class TestTuneCacheKeys:
         df = pipeline.default_dataflow(alg)
         base = pipeline._cache_key(alg, df, pipeline.ArrayConfig(),
                                    "float32", True, "pallas")
+        # (8, 16, 16) is chip-legal for fp32 at 16^3, so lower() runs
+        # the stored blocks unchanged
         tune_cache.store_variant(tune_cache.key_of(base),
-                                 blocks=(8, 8, 8), grid_order="mnk",
+                                 blocks=(8, 16, 16), grid_order="mnk",
                                  accum="scratch")
         pipeline.cache_clear()
         plain = pipeline.lower(alg, df, interpret=True)
-        assert plain.source == "tuned" and plain.blocks == (8, 8, 8)
+        assert plain.source == "tuned" and plain.blocks == (8, 16, 16)
         fused = pipeline.lower(alg, df, interpret=True,
                                fused_group="g:test")
-        assert fused.source == "analytical" and fused.blocks != (8, 8, 8)
+        assert fused.source == "analytical" and fused.blocks != (8, 16, 16)
         epi = pipeline.lower(alg, df, interpret=True, epilogue=("relu",))
         assert epi.source == "analytical"
 
@@ -407,8 +425,9 @@ class TestMergedKernel:
         assert gk.bm == gk.m              # whole-tensor degenerate phase
         seq = graph_executor.build(g, interpret=True, merge=False)
         ops = g.random_operands(0)
-        np.testing.assert_array_equal(np.asarray(acc(ops)),
-                                      np.asarray(seq(ops)))
+        got = np.asarray(acc(ops))
+        assert_fp32_close(got, g.reference(ops))
+        assert_fp32_close(got, seq(ops))
         acc.validate()
 
     def test_merged_nondivisible_m_blocks(self):
@@ -538,12 +557,12 @@ class TestMergedKernel:
                                   "float32", True, "pallas",
                                   fused_group="g:test")
         tune_cache.store_variant(tune_cache.key_of(key),
-                                 blocks=(4, 4, 4), grid_order="kmn",
+                                 blocks=(8, 16, 16), grid_order="kmn",
                                  accum="inplace")
         pipeline.cache_clear()
         fused = pipeline.lower(alg, df, interpret=True,
                                fused_group="g:test")
-        assert fused.source == "tuned" and fused.blocks == (4, 4, 4)
+        assert fused.source == "tuned" and fused.blocks == (8, 16, 16)
 
 
 # ---------------------------------------------------------------------------
@@ -673,11 +692,36 @@ class TestModelLayer:
         acc = graph_executor.build(g, interpret=True)
         assert len(acc.group_kernels) == 1
         out = np.asarray(acc(ops))
-        oracle = np.asarray(from_model.layer_oracle(ops))
-        assert np.array_equal(out, oracle)
+        assert_fp32_close(out, from_model.layer_oracle(ops))
         seq = graph_executor.build(g, interpret=True, merge=False)
-        assert np.array_equal(out, np.asarray(seq(ops)))
+        assert_fp32_close(out, seq(ops))
         acc.validate()
+
+    def test_model_layer_wide_ffn_tile_agreement_converges(self):
+        # h2o-danube-1.8b's d_model:d_ff = 2560:6912 at l=128 mixes
+        # whole-tensor edges (q, k, r1) with an MLP edge too wide for
+        # VMEM residency; agreement used to flip the shared nodes between
+        # the two forever.  Same width ratio (1:27) at a small l (still
+        # above the 16-row PE tile, which is what the narrowing meets),
+        # with a residency limit (budget // 8) that keeps the same split.
+        from repro.core.tiling import ArrayConfig
+        from repro.graph import from_model
+        g = from_model.transformer_layer_graph(l=32, d=16, f=432)
+        cfg = ArrayConfig(vmem_budget_bytes=8 * 16384)
+        assert 4 * 32 * 32 <= 16384 < 4 * 32 * 432
+        plan = plan_graph(g, cfg=cfg)
+        (grp,) = plan.groups
+        assert not grp.eligible and "VMEM" in grp.reason
+        for e in plan.edges:
+            if e.fused:
+                p, c = plan.nodes[e.producer], plan.nodes[e.consumer]
+                if e.side == "lhs":
+                    assert p.blocks[:2] == (c.blocks[0], c.blocks[2])
+        acc = graph_executor.build(g, plan=plan, cfg=cfg, interpret=True)
+        assert not acc.group_kernels
+        assert f"sequential {grp.name}: {grp.reason}" in acc.describe()
+        ops = g.random_operands(0)
+        assert_fp32_close(acc(ops), from_model.layer_oracle(ops))
 
     def test_model_layer_from_config(self):
         from repro.configs.registry import get_config

@@ -128,10 +128,10 @@ from repro.launch import specs, hlo_analysis
 from repro.configs import get_config
 
 # miniature production mesh (2x4) standing in for (16x16)
-from repro import jax_compat
-mesh = jax_compat.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cell = specs.input_specs("granite-8b", "train_4k", mesh)
-with jax_compat.set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     lowered = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                       out_shardings=cell.out_shardings,
                       donate_argnums=cell.donate).lower(*cell.args)
@@ -157,9 +157,11 @@ def test_dryrun_cell_smoke_8_devices():
 
 def test_input_specs_all_cells_constructible():
     """Every (arch x shape) cell must build its specs (no device state)."""
+    import jax
+
     from repro.launch import specs
-    from repro import jax_compat
-    mesh = jax_compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     n = 0
     for arch, shape in specs.all_cells():
         cell = specs.input_specs(arch, shape, mesh)
@@ -170,3 +172,18 @@ def test_input_specs_all_cells_constructible():
     skips = list(specs.skipped_cells())
     assert len(skips) == 6
     assert n + len(skips) == 40   # the full assignment grid
+
+
+def test_compile_cache_is_left_alone_off_the_chip(monkeypatch):
+    import jax
+
+    from repro.launch import cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    assert cache.enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+    assert cache.compile_cache_stats() == {"hits": 0, "misses": 0}
+    # the fixed in-checkout path, never a temporary one
+    assert cache.CACHE_DIR.name == ".jax_cache"
+    assert (cache.CACHE_DIR.parent / "chip_smoke.py").exists()

@@ -429,3 +429,16 @@ class TestBenchSchema:
     def test_speedup_computed(self):
         cell = _valid_doc()["cells"][0]
         assert cell["speedup"] == pytest.approx(2.0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_block_candidates_are_chip_legal(dtype):
+    from repro.kernels import stt_gemm
+
+    dims = (512, 6912, 2560)
+    analytical = stt_gemm.legal_blocks((16, 16, 16), dims, dtype)
+    cands = tuner.block_candidates(analytical, dims, dtype)
+    assert cands[0] == analytical
+    assert len(set(cands)) == len(cands)
+    for c in cands:
+        assert stt_gemm.legal_blocks(c, dims, dtype) == c
