@@ -9,8 +9,6 @@ job when a performance ratio regresses below its floor:
   * BENCH_tune.json  — schema ``repro.tune.report.validate_bench``;
     tuned-vs-untuned speedup >= TUNE_SPEEDUP_FLOOR per cell (a tuned
     pick must never lose to its own untuned baseline),
-  * BENCH_serve.json — schema ``repro.serve.report.validate_serve``;
-    continuous-vs-static throughput >= SERVE_SPEEDUP_FLOOR,
   * BENCH_graph.json — schema v4: fused-vs-unfused HBM ratio >= the
     modeled floor recorded in the document
     (``benchmarks.graph_fusion.HBM_RATIO_FLOOR``), *measured*
@@ -37,7 +35,6 @@ import sys
 ROOT = pathlib.Path(__file__).parent.parent
 
 TUNE_SPEEDUP_FLOOR = 1.0
-SERVE_SPEEDUP_FLOOR = 1.5
 
 
 def _load(name: str, problems: list) -> dict | None:
@@ -53,7 +50,6 @@ def _load(name: str, problems: list) -> dict | None:
 
 
 def check(problems: list) -> None:
-    from repro.serve.report import validate_serve
     from repro.tune.report import validate_bench
 
     tune = _load("BENCH_tune.json", problems)
@@ -65,16 +61,6 @@ def check(problems: list) -> None:
                 problems.append(
                     f"BENCH_tune.json: {cell.get('cell')} tuned/untuned "
                     f"speedup {sp:.2f} < floor {TUNE_SPEEDUP_FLOOR}")
-
-    serve = _load("BENCH_serve.json", problems)
-    if serve is not None:
-        problems += [f"BENCH_serve.json: {p}" for p in
-                     validate_serve(serve)]
-        sp = serve.get("speedup")
-        if sp is not None and sp < SERVE_SPEEDUP_FLOOR:
-            problems.append(
-                f"BENCH_serve.json: continuous/static speedup {sp:.2f} "
-                f"< floor {SERVE_SPEEDUP_FLOOR}")
 
     graph = _load("BENCH_graph.json", problems)
     if graph is not None:
@@ -161,8 +147,8 @@ def main() -> None:
         for p in problems:
             print(f"  {p}", file=sys.stderr)
         raise SystemExit(1)
-    print("bench-regress gates passed (tune schema+floor, serve "
-          "schema+floor, graph ratio+parity)")
+    print("bench-regress gates passed (tune schema+floor, graph "
+          "ratio+parity)")
 
 
 if __name__ == "__main__":

@@ -10,8 +10,6 @@ Sections:
          wall time; oracle parity)
   tune   — measured autotuning smoke: tuned vs untuned wall clock per cell,
          calibrated cycle model, BENCH_tune.json emission
-  serve  — continuous-batching vs static-batch serving load (open-loop,
-         mixed lengths; parity + speedup gate, BENCH_serve.json emission)
   graph  — fused vs unfused attention+MLP chain (HBM-bytes proxy floor +
          fp32 parity vs the explicit-schedule oracle, BENCH_graph.json)
   table3 — MM throughput comparison (XLA baselines + TPU roofline projection)
@@ -90,22 +88,6 @@ def main(argv=None) -> None:
         perf_iterate.run_tune_cells(smoke=True)
     except Exception:
         failures.append("tune")
-        traceback.print_exc()
-
-    _section("Serving load — continuous vs static batching")
-    try:
-        import json
-        import pathlib
-
-        from benchmarks import serve_load
-        from repro.serve.report import validate_serve
-        serve_load.main(["--smoke"])
-        doc = json.loads((pathlib.Path(__file__).parent.parent
-                          / "BENCH_serve.json").read_text())
-        problems = validate_serve(doc)
-        assert not problems, f"BENCH_serve.json invalid: {problems}"
-    except Exception:
-        failures.append("serve")
         traceback.print_exc()
 
     _section("Graph fusion — fused vs unfused attention+MLP chain")

@@ -1,7 +1,6 @@
 """Assigned-architecture configs (+ the paper's own tensor-algebra ops)."""
-from .base import (SERVE_MIXES, SHAPES, InputShape, ModelConfig, ServeMix,
-                   cells_for)
+from .base import SHAPES, InputShape, ModelConfig, cells_for
 from .registry import ARCH_IDS, all_configs, get_config
 
-__all__ = ["SERVE_MIXES", "SHAPES", "InputShape", "ModelConfig", "ServeMix",
-           "cells_for", "ARCH_IDS", "all_configs", "get_config"]
+__all__ = ["SHAPES", "InputShape", "ModelConfig", "cells_for", "ARCH_IDS",
+           "all_configs", "get_config"]

@@ -227,35 +227,3 @@ def cells_for(cfg: ModelConfig) -> Tuple[str, ...]:
 # ---------------------------------------------------------------------------
 # Serving workload mixes (open-loop load for the continuous-batching server)
 # ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class ServeMix:
-    """One open-loop serving workload: mixed prompt/output length buckets
-    sampled per request, Poisson arrivals at ``rate_rps`` (0 = burst: all
-    requests arrive at t=0, which is what the CI smoke uses so wall time
-    measures compute, not the arrival clock)."""
-
-    name: str
-    prompt_lens: Tuple[int, ...]   # sampled uniformly per request
-    output_lens: Tuple[int, ...]
-    requests: int
-    rate_rps: float = 0.0
-
-    @property
-    def arrival(self) -> str:
-        return "poisson" if self.rate_rps > 0 else "burst"
-
-    def max_context(self) -> int:
-        return max(self.prompt_lens) + max(self.output_lens)
-
-
-SERVE_MIXES = {
-    # CI smoke: tiny burst mix, long/short prompts and outputs interleaved
-    # so static batching pays padding + drain and continuous does not.
-    "smoke": ServeMix("smoke", prompt_lens=(8, 16, 24), output_lens=(4, 24),
-                      requests=12),
-    # benchmark default: open-loop Poisson with a wider spread
-    "mixed": ServeMix("mixed", prompt_lens=(8, 16, 24, 40),
-                      output_lens=(4, 8, 16, 32), requests=32,
-                      rate_rps=8.0),
-}

@@ -23,6 +23,7 @@ from typing import Any, Dict, Mapping, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..compile import pipeline
 from ..core.costmodel import GraphCostReport
@@ -81,6 +82,9 @@ class GraphAccelerator:
     Nodes belonging to a merged group (``group_kernels``) do not
     dispatch individually: the whole chain runs as one Pallas kernel at
     the group's last stage, intermediates never leaving VMEM.
+
+    While the profiler runs, a call is a ``graph.call`` span holding one
+    ``graph.unit`` span per dispatch unit (args ``name``, ``kind``).
     """
 
     graph: AlgebraGraph
@@ -104,6 +108,10 @@ class GraphAccelerator:
         return jnp.dtype(self.plan.dtype)
 
     def __call__(self, operands: Mapping[str, jax.Array]) -> jax.Array:
+        with TraceAnnotation("graph.call"):
+            return self._execute(operands)
+
+    def _execute(self, operands: Mapping[str, jax.Array]) -> jax.Array:
         missing = [e for e in self.graph.inputs if e not in operands]
         if missing:
             raise ValueError(f"missing graph input(s): {missing}")
@@ -135,7 +143,9 @@ class GraphAccelerator:
             later = []
             for kind, u in pending:
                 if all(e in values for e in self._unit_inputs(kind, u)):
-                    self._run_unit(kind, u, values)
+                    with TraceAnnotation("graph.unit", name=u.name,
+                                         kind=kind):
+                        self._run_unit(kind, u, values)
                 else:
                     later.append((kind, u))
             if len(later) == len(pending):   # pragma: no cover

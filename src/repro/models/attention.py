@@ -166,6 +166,7 @@ def make_kv_cache(cfg: ModelConfig, batch: int, seq_len: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@jax.named_scope("attention")
 def apply_attention(p: Dict, x: jax.Array, cfg: ModelConfig, *,
                     kv_x: Optional[jax.Array] = None,
                     causal: bool = True,

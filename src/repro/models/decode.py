@@ -151,6 +151,10 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
     vectorize accordingly and each row computes exactly what it would with
     that row's scalar position.
 
+    Named scopes for the compiled step's op metadata: ``attention`` and
+    ``mlp`` (on ``apply_attention`` / ``apply_mlp`` / ``apply_moe``
+    themselves) and ``head`` (final norm and logits).
+
     Returns (logits (B, vocab), updated cache)."""
     compute = jnp.dtype(cfg.dtype)
     pos = cache["pos"]
@@ -295,11 +299,13 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
     else:
         raise ValueError(cfg.family)
 
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    w_out = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
-    logits = jnp.dot(x.astype(compute), w_out.astype(compute),
-                     preferred_element_type=jnp.float32)
-    logits = shard(logits, ("pod", "data"), None, "model")
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        w_out = (params["embed"].T if cfg.tie_embeddings
+                 else params["unembed"])
+        logits = jnp.dot(x.astype(compute), w_out.astype(compute),
+                         preferred_element_type=jnp.float32)
+        logits = shard(logits, ("pod", "data"), None, "model")
     return logits[:, 0], new_cache
 
 

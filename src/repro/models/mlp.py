@@ -34,6 +34,7 @@ def init_mlp(key, cfg: ModelConfig, n_layers: int) -> Dict:
     }
 
 
+@jax.named_scope("mlp")
 def apply_mlp(p: Dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     compute = jnp.dtype(cfg.dtype)
     if cfg.explicit_collectives and cfg.sequence_parallel:
@@ -117,6 +118,7 @@ def _dispatch_indices(top_idx: jax.Array, n_experts: int, capacity: int
     return buf, keep.reshape(t, k)
 
 
+@jax.named_scope("mlp")
 def apply_moe(p: Dict, x: jax.Array, cfg: ModelConfig
               ) -> Tuple[jax.Array, jax.Array]:
     """x: (B, S, D) -> (out, aux_loss).  Router in fp32.

@@ -9,21 +9,18 @@ Layered like a real inference stack:
   indirection, shared pool) + mesh placement via the partition solver;
 * ``slots``   — fixed-capacity continuous-batching slot engine over the
   paged cache (insert/evict without draining or recompiling);
-* ``server``  — thread-safe async dispatch loop with per-request futures;
-* ``report``  — BENCH_serve.json schema + validator.
+* ``server``  — thread-safe async dispatch loop with per-request futures.
 """
-from . import engine, pages, report, server, slots
+from . import engine, pages, server, slots
 from .engine import AcceleratorEngine, DecodeEngine, ServeConfig
 from .pages import PagedKVCache, PageLayout, place_pools, solve_page_placement
-from .report import SERVE_SCHEMA_VERSION, serve_entry, validate_serve
 from .server import ContinuousServer, Request, RequestFuture
 from .slots import ResultTokens, SlotEngine
 
 __all__ = [
-    "engine", "pages", "report", "server", "slots",
+    "engine", "pages", "server", "slots",
     "AcceleratorEngine", "DecodeEngine", "ServeConfig",
     "PagedKVCache", "PageLayout", "place_pools", "solve_page_placement",
-    "SERVE_SCHEMA_VERSION", "serve_entry", "validate_serve",
     "ContinuousServer", "Request", "RequestFuture",
     "ResultTokens", "SlotEngine",
 ]

@@ -14,6 +14,13 @@ parallelism comes from the batch, not from threads racing the device):
 * per-step results arrive as one packed :class:`ResultTokens` array
   (single device→host copy); finished sequences (EOS or length budget)
   are evicted without draining the batch, and their futures resolve.
+
+Tracing: each loop iteration with work is a ``serve.loop`` profiler span
+holding ``serve.admit`` (args ``admitted``, ``queued``), the engine's
+``serve.step`` and ``serve.emit`` (``tokens``, ``evicted``); an idle
+wait is ``serve.wait``.  The spans record nothing while the profiler is
+off.  ``RequestFuture.admitted_at`` splits a request's time to first
+token into its queue wait and its prefill.
 """
 from __future__ import annotations
 
@@ -22,9 +29,10 @@ import itertools
 import queue as queue_mod
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .slots import SlotEngine
 
@@ -48,6 +56,9 @@ class RequestFuture:
         self._done = threading.Event()
         self._tokens: List[int] = []
         self._error: Optional[BaseException] = None
+        #: perf_counter when the scheduler took the request off the queue
+        #: for its prefill (None until then)
+        self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
 
@@ -174,15 +185,17 @@ class ContinuousServer:
             except queue_mod.Empty:
                 break
             req = fut.request
+            fut.admitted_at = time.perf_counter()
             try:
                 res = self.engine.insert(req.prompt,
                                          max_new_tokens=req.max_new_tokens,
-                                         frontend=req.frontend)
+                                         frontend=req.frontend, rid=req.rid)
             except Exception as err:        # bad request (e.g. too long)
                 fut._fail(err)
                 self._request_done()
                 continue
             if res is None:                 # pool exhausted: wait for evicts
+                fut.admitted_at = None
                 held.append(fut)
                 self.stats["admission_stalls"] += 1
                 break
@@ -215,33 +228,50 @@ class ContinuousServer:
             if self._inflight == 0:
                 self._all_done.set()
 
+    def _emit(self, result) -> Tuple[int, int]:
+        """Hand each live slot's token to its future; evict the slots that
+        finished.  Returns (tokens emitted, slots evicted)."""
+        tokens = evicted = 0
+        for slot, fut in list(self._resident.items()):
+            if not result.valid_at(slot):
+                continue
+            tok = result.token_at(slot)
+            fut._emit(tok)
+            tokens += 1
+            self._budget[slot] -= 1
+            done = self._finished_on(
+                fut, tok,
+                emitted=fut.request.max_new_tokens - self._budget[slot])
+            if done or self._budget[slot] <= 0:
+                self.engine.evict(slot)
+                evicted += 1
+                del self._resident[slot], self._budget[slot]
+                fut._finish()
+                self._request_done()
+        self.stats["tokens"] += tokens
+        self.stats["evictions"] += evicted
+        return tokens, evicted
+
     def _run(self) -> None:
         while not self._stop.is_set():
-            self._admit()
-            if not self._resident:
-                if self._queue.empty():
+            if not self._resident and self._queue.empty():
+                with TraceAnnotation("serve.wait"):
                     self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                self._wake.clear()
                 continue
-            result = self.engine.step()
-            self.stats["steps"] += 1
-            self.stats["occupancy_sum"] += self.engine.occupancy
-            for slot, fut in list(self._resident.items()):
-                if not result.valid_at(slot):
+            with TraceAnnotation("serve.loop"):
+                with TraceAnnotation("serve.admit") as span:
+                    admitted = self._admit()
+                    span.set_metadata(admitted=admitted,
+                                      queued=self._queue.qsize())
+                if not self._resident:
                     continue
-                tok = result.token_at(slot)
-                fut._emit(tok)
-                self.stats["tokens"] += 1
-                self._budget[slot] -= 1
-                done = self._finished_on(
-                    fut, tok,
-                    emitted=fut.request.max_new_tokens - self._budget[slot])
-                if done or self._budget[slot] <= 0:
-                    self.engine.evict(slot)
-                    self.stats["evictions"] += 1
-                    del self._resident[slot], self._budget[slot]
-                    fut._finish()
-                    self._request_done()
+                result = self.engine.step()
+                self.stats["steps"] += 1
+                self.stats["occupancy_sum"] += self.engine.occupancy
+                with TraceAnnotation("serve.emit") as span:
+                    tokens, evicted = self._emit(result)
+                    span.set_metadata(tokens=tokens, evicted=evicted)
         # on shutdown without drain: fail whatever is left
         leftovers = list(self._resident.values())
         self._resident.clear()

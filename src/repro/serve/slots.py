@@ -18,6 +18,15 @@ sequences of different lengths coexist in one step.
 Every step returns a :class:`ResultTokens`: tokens + validity + lengths
 packed into **one** array — one device→host copy per step is much
 faster than three (the JetStream observation).
+
+Tracing: ``insert`` records the profiler spans ``serve.prefill`` (args
+``rid``, ``tokens``) and ``serve.cache_insert`` (``pages``), ``step``
+records ``serve.step`` (``live``) around ``serve.fetch``, the host read
+of the packed result; they record nothing while the profiler is off.
+Inside the step program, ``jax.named_scope`` names ``cache_gather``,
+``sample`` and ``cache_scatter`` (``decode_step`` names ``attention``,
+``mlp`` and ``head``); :meth:`SlotEngine.step_hlo_text` returns the
+compiled step, whose op metadata carries those scopes.
 """
 from __future__ import annotations
 
@@ -28,6 +37,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..configs.base import ModelConfig
@@ -106,6 +116,7 @@ class SlotEngine:
         self._tokens = np.zeros((c, 1), np.int32)
         self._pos = np.zeros((c,), np.int32)
         self._active = np.zeros((c,), bool)
+        self._live = 0                     # == self._active.sum()
         #: device twin of (tokens, pos, active, table).  The jitted step
         #: carries tokens/pos forward on device, so steady-state decode
         #: does ZERO host->device transfers — the twin re-syncs from the
@@ -131,7 +142,7 @@ class SlotEngine:
 
     @property
     def occupancy(self) -> float:
-        return float(self._active.mean())
+        return self._live / self.capacity
 
     def position(self, slot: int) -> int:
         return int(self._pos[slot])
@@ -149,17 +160,20 @@ class SlotEngine:
                 .astype(jnp.int32))
 
         def step(params, tokens, pos, active, table, pools, lanes, key):
-            views = lay.gather_views(pools, table)
+            with jax.named_scope("cache_gather"):
+                views = lay.gather_views(pools, table)
             cache: Dict[str, Any] = _nest({**views, **lanes})
             cache["pos"] = pos
             logits, new_cache = dec.decode_step(params, tokens, cache, cfg)
             flat_new = _flatten_cache(new_cache)
-            pools2 = lay.scatter_written(
-                pools, table, {p: flat_new[p] for p, _ in lay.paged},
-                pos, active)
-            lanes2 = lay.freeze_inactive(
-                lanes, {p: flat_new[p] for p in lanes}, active)
-            tok = sample(logits, key)
+            with jax.named_scope("cache_scatter"):
+                pools2 = lay.scatter_written(
+                    pools, table, {p: flat_new[p] for p, _ in lay.paged},
+                    pos, active)
+                lanes2 = lay.freeze_inactive(
+                    lanes, {p: flat_new[p] for p in lanes}, active)
+            with jax.named_scope("sample"):
+                tok = sample(logits, key)
             new_pos = jnp.where(active, pos + 1, pos)
             new_tokens = jnp.where(active[:, None], tok, tokens)
             packed = jnp.concatenate(
@@ -171,14 +185,15 @@ class SlotEngine:
 
     # -- slot lifecycle ----------------------------------------------------
     def insert(self, prompt: np.ndarray, *, max_new_tokens: int,
-               frontend: Optional[np.ndarray] = None
+               frontend: Optional[np.ndarray] = None, rid: int = -1
                ) -> Optional[Tuple[int, int]]:
         """Prefill one request and land it in a free slot.
 
         ``prompt``: (s0,) int32.  Returns ``(slot, first_token)`` — the
         first token is sampled from the prefill logits, exactly like
         ``DecodeEngine.generate`` — or None when no slot or not enough
-        free pages (the caller keeps the request queued).
+        free pages (the caller keeps the request queued).  ``rid`` only
+        labels the request's ``serve.prefill`` span.
         """
         s0 = int(prompt.shape[-1])
         if s0 + max_new_tokens > self.max_context:
@@ -199,20 +214,24 @@ class SlotEngine:
         if not self.cache.alloc(slot, s0 + max_new_tokens):
             return None
         fe = None if frontend is None else jnp.asarray(frontend)
-        logits, cache_p = self._prefill(
-            self.params, jnp.asarray(prompt, jnp.int32)[None],
-            frontend=fe, max_len=self.max_context)
-        self._prefill_count += 1
-        if self.serve_cfg.temperature <= 0.0:
-            tok = int(jnp.argmax(logits, axis=-1)[0])
-        else:
-            key = jax.random.fold_in(self._base_key, self._prefill_count)
-            tok = int(jax.random.categorical(
-                key, logits / self.serve_cfg.temperature, axis=-1)[0])
-        self.cache.insert(slot, cache_p)
+        with TraceAnnotation("serve.prefill", rid=rid, tokens=s0):
+            logits, cache_p = self._prefill(
+                self.params, jnp.asarray(prompt, jnp.int32)[None],
+                frontend=fe, max_len=self.max_context)
+            self._prefill_count += 1
+            if self.serve_cfg.temperature <= 0.0:
+                tok = int(jnp.argmax(logits, axis=-1)[0])
+            else:
+                key = jax.random.fold_in(self._base_key, self._prefill_count)
+                tok = int(jax.random.categorical(
+                    key, logits / self.serve_cfg.temperature, axis=-1)[0])
+        pages = self.cache.pages_needed(s0 + max_new_tokens)
+        with TraceAnnotation("serve.cache_insert", pages=pages):
+            self.cache.insert(slot, cache_p)
         self._pos[slot] = s0
         self._tokens[slot, 0] = tok
         self._active[slot] = True
+        self._live += 1
         self._dev = None
         return slot, tok
 
@@ -220,6 +239,7 @@ class SlotEngine:
         """Free a finished slot's pages; the decode batch keeps running
         for the other slots (no drain, no recompile)."""
         self.cache.free(slot)
+        self._live -= int(self._active[slot])
         self._active[slot] = False
         self._pos[slot] = 0
         self._tokens[slot, 0] = 0
@@ -239,24 +259,38 @@ class SlotEngine:
                 return lambda x: jax.device_put(x, rep)
         return jnp.asarray
 
+    def _step_args(self, key) -> Tuple:
+        """The decode step's arguments; re-syncs the device twin from the
+        host mirrors after an insert or evict."""
+        if self._dev is None:
+            put = self._twin_put()
+            self._dev = (put(self._tokens), put(self._pos),
+                         put(self._active), put(self.cache.device_table()))
+        return (self.params, *self._dev, self.cache.pools, self.cache.lanes,
+                key)
+
     def step(self) -> ResultTokens:
         """Advance every live slot one token; packed device→host copy."""
         key = self._base_key
         if self.serve_cfg.temperature > 0.0:
             key = jax.random.fold_in(self._base_key, -1 - self._step_count)
-        if self._dev is None:              # insert/evict since last step
-            put = self._twin_put()
-            self._dev = (put(self._tokens), put(self._pos),
-                         put(self._active), put(self.cache.device_table()))
-        tokens, pos, active, table = self._dev
-        packed, (tokens, pos), pools, lanes = self._step_fn(
-            self.params, tokens, pos, active, table,
-            self.cache.pools, self.cache.lanes, key)
-        self._dev = (tokens, pos, active, table)
-        self.cache.pools, self.cache.lanes = pools, lanes
-        self._step_count += 1
-        data = np.asarray(packed)          # the one device->host copy
-        live = self._active
-        self._tokens[live, 0] = data[live, 0]
-        self._pos[live] += 1
+        with TraceAnnotation("serve.step", live=self._live):
+            packed, (tokens, pos), pools, lanes = self._step_fn(
+                *self._step_args(key))
+            self._dev = (tokens, pos, *self._dev[2:])
+            self.cache.pools, self.cache.lanes = pools, lanes
+            self._step_count += 1
+            with TraceAnnotation("serve.fetch"):
+                data = np.asarray(packed)  # the one device->host copy
+            live = self._active
+            self._tokens[live, 0] = data[live, 0]
+            self._pos[live] += 1
         return ResultTokens(data)
+
+    def step_hlo_text(self) -> str:
+        """The compiled decode step's optimized HLO for the engine's
+        current shapes.  Each op's ``metadata={op_name=...}`` carries the
+        step's named scopes.  Once the step has run this is the jit's
+        own executable: nothing is compiled."""
+        return self._step_fn.lower(
+            *self._step_args(self._base_key)).compile().as_text()
