@@ -15,10 +15,15 @@ SWA archs use rolling caches of ``window`` slots; prefill fills them with
 the last ``window`` positions (valid because window divides the assigned
 sequence lengths).  decode_step lowers the ``serve_step`` of the dry-run's
 decode cells: one new token against a seq_len-deep cache.
+
+Where the step's per-layer self-attention K/V come from is the caller's
+(:class:`LayerKV`): the per-call path scans the dense leaves above and
+keeps every updated slice; the serving slot engine scans layer indices,
+reads each layer from its page pool and keeps only the written rows.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -141,8 +146,30 @@ def init_cache(params: Dict, cfg: ModelConfig, batch: int, seq_len: int, *,
 # decode step
 # ---------------------------------------------------------------------------
 
+class LayerKV(NamedTuple):
+    """How the layer loop gets one layer's self-attention K/V and what it
+    keeps of the updated ones.
+
+    ``cache["self"]`` / ``cache["shared"]`` hold what the loop slices per
+    layer (a scan input, or a static index for hybrid's shared block);
+    ``read(group, x)`` turns one layer's slice ``x`` into ``{"k", "v"}``
+    ``(B, S, kv)`` blocks, and ``keep(group, kv, pos)`` picks what the
+    loop stacks of the updated blocks — the step returns that stack under
+    ``group``."""
+
+    read: Callable[[str, Any], Dict[str, jax.Array]]
+    keep: Callable[[str, Dict[str, jax.Array], jax.Array], Any]
+
+
+#: the per-call cache: dense ``(L, B, S, kv)`` leaves, every updated
+#: layer slice kept, so the step returns the whole updated cache
+DENSE_KV = LayerKV(read=lambda group, kv: kv,
+                   keep=lambda group, kv, pos: kv)
+
+
 def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
-                cfg: ModelConfig) -> Tuple[jax.Array, Dict[str, Any]]:
+                cfg: ModelConfig, kv: LayerKV = DENSE_KV
+                ) -> Tuple[jax.Array, Dict[str, Any]]:
     """tokens: (B, 1) int32 — one new token per sequence.
 
     ``cache["pos"]`` may be a scalar (all sequences at the same position,
@@ -155,6 +182,10 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
     ``mlp`` (on ``apply_attention`` / ``apply_mlp`` / ``apply_moe``
     themselves) and ``head`` (final norm and logits).
 
+    ``kv`` says where each layer's self-attention K/V come from and what
+    the step returns of them (:class:`LayerKV`; the dense cache by
+    default).  The attention math is the same either way.
+
     Returns (logits (B, vocab), updated cache)."""
     compute = jnp.dtype(cfg.dtype)
     pos = cache["pos"]
@@ -162,12 +193,17 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
     x = shard(x, ("pod", "data"), None, None)
     new_cache: Dict[str, Any] = {"pos": pos + 1}
 
+    def self_attn(p, x, group, kv_l):
+        h, kv_new = attn.apply_attention(p, x, cfg,
+                                         cache=kv.read(group, kv_l), pos=pos)
+        return h, kv.keep(group, kv_new, pos)
+
     if cfg.family in ("dense", "moe"):
         def body(pl_and_kv, x):
-            pl_, ck, cv = pl_and_kv
-            h, kv_new = attn.apply_attention(
-                pl_["attn"], rmsnorm(x, pl_["ln1"], cfg.norm_eps), cfg,
-                cache={"k": ck, "v": cv}, pos=pos)
+            pl_, kv_l = pl_and_kv
+            h, kv_new = self_attn(
+                pl_["attn"], rmsnorm(x, pl_["ln1"], cfg.norm_eps), "self",
+                kv_l)
             x = x + h
             if "router" in pl_["ffn"]:
                 h, _ = mlp_mod.apply_moe(
@@ -176,10 +212,9 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
                 h = mlp_mod.apply_mlp(
                     pl_["ffn"], rmsnorm(x, pl_["ln2"], cfg.norm_eps), cfg)
             return x + h, jnp.zeros((), jnp.float32), kv_new
-        x, _, kv = scan_layers(
-            (params["layers"], cache["self"]["k"], cache["self"]["v"]),
-            x, lambda inp, x: body(inp, x), cfg)
-        new_cache["self"] = kv
+        x, _, kv_out = scan_layers((params["layers"], cache["self"]),
+                                   x, lambda inp, x: body(inp, x), cfg)
+        new_cache["self"] = kv_out
 
     elif cfg.family == "ssm":
         def body(pl_and_c, x):
@@ -218,10 +253,9 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
                 x, lambda inp, x: body(inp, x), cfg)
             ssm_new.append(c)
             ps = params["shared"]
-            h, kv_new = attn.apply_attention(
-                ps["attn"], rmsnorm(x, ps["ln1"], cfg.norm_eps), cfg,
-                cache=jax.tree.map(lambda a: a[gi], cache["shared"]),
-                pos=pos)
+            h, kv_new = self_attn(
+                ps["attn"], rmsnorm(x, ps["ln1"], cfg.norm_eps), "shared",
+                jax.tree.map(lambda a: a[gi], cache["shared"]))
             x = x + h
             h = mlp_mod.apply_mlp(ps["mlp"],
                                   rmsnorm(x, ps["ln2"], cfg.norm_eps), cfg)
@@ -240,10 +274,10 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
 
     elif cfg.family == "encdec":
         def body(inp, x):
-            pl_, ck, cv, xk, xv = inp
-            h, kv_new = attn.apply_attention(
-                pl_["attn"], rmsnorm(x, pl_["ln1"], cfg.norm_eps), cfg,
-                cache={"k": ck, "v": cv}, pos=pos)
+            pl_, kv_l, xk, xv = inp
+            h, kv_new = self_attn(
+                pl_["attn"], rmsnorm(x, pl_["ln1"], cfg.norm_eps), "self",
+                kv_l)
             x = x + h
             h, _ = attn.apply_attention(
                 pl_["cross"], rmsnorm(x, pl_["ln2"], cfg.norm_eps), cfg,
@@ -253,11 +287,11 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
             h = mlp_mod.apply_mlp(pl_["ffn"],
                                   rmsnorm(x, pl_["ln3"], cfg.norm_eps), cfg)
             return x + h, jnp.zeros((), jnp.float32), kv_new
-        x, _, kv = scan_layers(
-            (params["layers"], cache["self"]["k"], cache["self"]["v"],
-             cache["cross"]["k"], cache["cross"]["v"]),
+        x, _, kv_out = scan_layers(
+            (params["layers"], cache["self"], cache["cross"]["k"],
+             cache["cross"]["v"]),
             x, lambda inp, x: body(inp, x), cfg)
-        new_cache["self"] = kv
+        new_cache["self"] = kv_out
         new_cache["cross"] = cache["cross"]
 
     elif cfg.family == "vlm":
@@ -271,10 +305,10 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
             cache["self"])
 
         def body(inp, x):
-            pl_, ck, cv = inp
-            h, kv_new = attn.apply_attention(
-                pl_["attn"], rmsnorm(x, pl_["ln1"], cfg.norm_eps), cfg,
-                cache={"k": ck, "v": cv}, pos=pos)
+            pl_, kv_l = inp
+            h, kv_new = self_attn(
+                pl_["attn"], rmsnorm(x, pl_["ln1"], cfg.norm_eps), "self",
+                kv_l)
             x = x + h
             h = mlp_mod.apply_mlp(pl_["ffn"],
                                   rmsnorm(x, pl_["ln2"], cfg.norm_eps), cfg)
@@ -288,11 +322,11 @@ def decode_step(params: Dict, tokens: jax.Array, cache: Dict[str, Any],
                 kv_x=x,  # marker: K/V from static image cache
                 cache=jax.tree.map(lambda a: a[gi], cache["cross"]), pos=pos)
             x = x + jnp.tanh(cl["gate"]) * h
-            x, _, kv = scan_layers(
+            x, _, kv_out = scan_layers(
                 (jax.tree.map(lambda a: a[gi], grouped),
-                 cgrouped["k"][gi], cgrouped["v"][gi]),
+                 jax.tree.map(lambda a: a[gi], cgrouped)),
                 x, lambda inp, x: body(inp, x), cfg)
-            kv_groups.append(kv)
+            kv_groups.append(kv_out)
         new_cache["self"] = jax.tree.map(
             lambda *xs: jnp.concatenate(xs, 0), *kv_groups)
         new_cache["cross"] = cache["cross"]

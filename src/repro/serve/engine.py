@@ -58,7 +58,7 @@ class DecodeEngine:
 
         ``cache_len`` overrides the decode cache's context budget (default
         ``S0 + max_new_tokens``).  The continuous-batching slot engine
-        gathers fixed-length page views, so its sequential parity oracle
+        reads fixed-length page views, so its sequential parity oracle
         is this method with ``cache_len`` pinned to the engine's
         ``max_context`` — same cache shape, bit-identical math."""
         scfg = self.serve_cfg

@@ -6,25 +6,31 @@ wrong shape twice over: every slot pays for the longest context whether
 it uses it or not, and insert/evict would reallocate the batch.  This
 module restructures the sequence-axis caches into **pages**:
 
-* one shared pool per K/V leaf, ``(total_pages + 1, page, L * kv)`` — a
-  page holds ``page_size`` token positions across *all* layers, and the
-  last physical page is a scratch page that absorbs writes from inactive
-  slots and backs unmapped table entries;
+* one shared pool per K/V leaf, ``(total_pages + 1, L, page, kv)`` — a
+  page holds ``page_size`` token positions across *all* layers, each
+  layer's rows one contiguous block, and the last physical page is a
+  scratch page that absorbs writes from inactive slots and backs
+  unmapped table entries;
 * a host-managed page table ``(capacity, pages_per_slot)`` with a free
   list — long and short sequences draw from the same pool, so a slot
   only reserves ``ceil((prompt + max_new) / page)`` pages;
-* gather/scatter through the same index-map machinery the Pallas kernels
-  use (``kernels/paged.py``: scalar-prefetched page table feeding
-  BlockSpec index maps, with a bit-identical jnp twin for CPU).
+* the decode step works on the pools in place: its layer loop reads one
+  layer's K/V for every slot straight from the pool through the page
+  table (``kernels/paged.paged_gather``, a
+  ``(capacity, seq_len, kv)`` block), and only the row each slot writes
+  leaves the loop; ``scatter_written`` puts those rows into the donated
+  pools.
 
 Cache leaves without a sequence axis (SSM conv/state, static cross K/V)
 are **lane pools**: the slot index is their batch axis directly.
 
-Bit-exactness contract: gathering a slot's pages yields exactly the
-dense cache the per-call path would hold (unmapped positions read the
-scratch page, whose garbage is masked to an exact zero contribution by
-the position-validity masks in ``_decode_attn``), so continuous decode
-reproduces sequential decode token-for-token.
+Bit-exactness contract: a layer's read of a slot's pages yields exactly
+that layer's slice of the dense cache the per-call path would hold
+(unmapped positions read the scratch page, whose garbage is masked to an
+exact zero contribution by the position-validity masks in
+``_decode_attn``), and the step writes the same row into it before
+attending, so continuous decode reproduces sequential decode
+token-for-token.
 """
 from __future__ import annotations
 
@@ -38,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import paged as paged_kernels
+from ..models.decode import LayerKV
 
 #: decode-cache paths whose leaves carry a sequence axis (axis 2 of an
 #: ``(Lx, B, S, kv)`` leaf) and are therefore paged; everything else
@@ -77,9 +84,9 @@ class PageLayout:
     page_size: int
     pages_per_slot: int            # logical pages in every slot's view
     total_pages: int               # physical pages (excluding scratch)
-    seq_len: int                   # gathered view length per slot
+    seq_len: int                   # cache positions per slot
     #: paged leaves: path -> (stack, feat, dtype name); pool is
-    #: (total_pages + 1, page, stack * feat)
+    #: (total_pages + 1, stack, page, feat)
     paged: Tuple[Tuple[Tuple[str, ...], Tuple[int, int, str]], ...]
     #: lane leaves: path -> (shape, dtype name); slot index is axis 1
     lanes: Tuple[Tuple[Tuple[str, ...], Tuple[Tuple[int, ...], str]], ...]
@@ -89,28 +96,43 @@ class PageLayout:
         return self.total_pages
 
     # -- pure device-side ops (used inside the jitted decode step) -------
-    def gather_views(self, pools: Dict[Tuple[str, ...], jax.Array],
-                     table: jax.Array) -> Dict[Tuple[str, ...], jax.Array]:
-        """pools + page table -> per-slot contiguous cache views
-        ``(stack, capacity, seq_len, feat)`` (what decode_step expects)."""
-        views = {}
-        for path, (stack, feat, _) in self.paged:
-            v = paged_kernels.paged_gather(pools[path], table)
-            v = v.reshape(self.capacity, self.seq_len, stack, feat)
-            views[path] = v.transpose(2, 0, 1, 3)
-        return views
+    def layer_inputs(self) -> Dict[Tuple[str, ...], np.ndarray]:
+        """What the decode step's layer loop slices for each paged leaf:
+        the layer indices ``0 .. stack - 1`` (``layer_kv`` reads them)."""
+        return {path: np.arange(stack, dtype=np.int32)
+                for path, (stack, _, _) in self.paged}
+
+    def layer_kv(self, pools: Dict[Tuple[str, ...], jax.Array],
+                 table: jax.Array) -> LayerKV:
+        """decode_step's per-layer K/V over the pools: ``read`` gathers
+        layer ``l``'s ``(capacity, seq_len, feat)`` block of every slot
+        from the pool through the page table; ``keep`` returns only the
+        row each slot wrote, ``(capacity, feat)``."""
+        def read(group, layer):
+            with jax.named_scope("cache_gather"):
+                return {leaf: paged_kernels.paged_gather(
+                            pools[(group, leaf)], table, layer[leaf])
+                        for leaf in layer}
+
+        def keep(group, kv, pos):
+            slot_pos = pos.astype(jnp.int32) % self.seq_len
+            rows = jnp.arange(self.capacity)
+            return {leaf: a[rows, slot_pos] for leaf, a in kv.items()}
+
+        return LayerKV(read=read, keep=keep)
 
     def scatter_written(self, pools: Dict[Tuple[str, ...], jax.Array],
-                        table: jax.Array, new_views: Dict[Tuple[str, ...],
-                                                          jax.Array],
+                        table: jax.Array, written: Dict[Tuple[str, ...],
+                                                        jax.Array],
                         pos: jax.Array, active: jax.Array
                         ) -> Dict[Tuple[str, ...], jax.Array]:
         """Write back the single token position each slot just produced.
 
-        ``new_views`` are decode_step's updated caches (the gathered view
-        with one write at ``pos % seq_len`` per slot); only that position
-        flows back to the pool — inactive slots are pointed at the
-        scratch page so the write is an exact no-op for live data."""
+        ``written`` are the rows decode_step kept, ``(stack, capacity,
+        feat)`` per paged leaf (``layer_kv``'s ``keep``); each slot's row
+        lands at ``pos % seq_len`` of its pages — inactive slots are
+        pointed at the scratch page so the write is an exact no-op for
+        live data.  With the pools donated the write is in place."""
         slot_pos = pos.astype(jnp.int32) % self.seq_len
         lpage = slot_pos // self.page_size
         off = slot_pos % self.page_size
@@ -118,14 +140,9 @@ class PageLayout:
         pid = table[rows, lpage]
         pid = jnp.where(active, pid, self.scratch_page)
         out = dict(pools)
-        for path, (stack, feat, _) in self.paged:
-            v = new_views[path]                      # (stack, C, S, feat)
-            written = jnp.take_along_axis(
-                v, slot_pos[None, :, None, None], axis=2)[:, :, 0]
-            written = written.transpose(1, 0, 2).reshape(
-                self.capacity, stack * feat)
+        for path, _ in self.paged:
             out[path] = paged_kernels.paged_scatter_token(
-                pools[path], pid, off, written)
+                pools[path], pid, off, written[path].transpose(1, 0, 2))
         return out
 
     def freeze_inactive(self, lanes: Dict[Tuple[str, ...], jax.Array],
@@ -188,7 +205,7 @@ class PagedKVCache:
             seq_len=seq_len, paged=tuple(paged_meta), lanes=tuple(lane_meta))
         lay = self.layout
         self.pools = {
-            path: jnp.zeros((total_pages + 1, page_size, stack * feat), dt)
+            path: jnp.zeros((total_pages + 1, stack, page_size, feat), dt)
             for path, (stack, feat, dt) in lay.paged}
         self.lanes = {path: jnp.zeros(shape, dt)
                       for path, (shape, dt) in lay.lanes}
@@ -252,10 +269,11 @@ class PagedKVCache:
             out_pools = dict(pools)
             for path, (stack, feat, _) in lay.paged:
                 leaf = flat[path]                   # (stack, 1, S, feat)
-                rows = leaf[:, 0].transpose(1, 0, 2).reshape(
-                    lay.pages_per_slot, lay.page_size, stack * feat)
+                pages = leaf[:, 0].reshape(
+                    stack, lay.pages_per_slot, lay.page_size, feat)
                 out_pools[path] = pools[path].at[idx].set(
-                    rows[:n].astype(pools[path].dtype))
+                    pages[:, :n].transpose(1, 0, 2, 3).astype(
+                        pools[path].dtype))
             out_lanes = dict(lanes)
             for path, _ in lay.lanes:
                 out_lanes[path] = lanes[path].at[:, slot].set(
@@ -269,7 +287,8 @@ class PagedKVCache:
         into the slot's reserved pages + lane rows.  Pure copies, fused
         into one jitted dispatch; the jit cache is keyed on the page
         count (bounded by pages_per_slot), never on occupancy — the
-        decode step's cache stays untouched."""
+        decode step's cache stays untouched.  The pools are not donated
+        here, so each insert writes whole new pools."""
         flat = _flatten_cache(cache)
         with self._lock:
             ids = list(self._slot_pages.get(slot, ()))
@@ -299,7 +318,8 @@ def solve_page_placement(cfg, layout: PageLayout,
     algebra (``repro.generate``) yields the CommPlan whose
     ``plan.solve_partition`` decides which mesh axis shards the batch —
     and pages belong to slots, so the page axis of every pool shards over
-    that axis.  Returns ``(PartitionSolution, PartitionSpec)``.
+    that axis.  Returns ``(PartitionSolution, PartitionSpec)``; the spec
+    shards the page axis, the pools' first.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -325,17 +345,16 @@ def place_pools(cache: PagedKVCache, mesh, spec) -> None:
     the pool keeps its scratch page, so the page axis is padded up to a
     multiple of the axis size before placement.  The pools are placed as
     ``P(axis)``, the spelling the jitted decode step returns them in:
-    ``P(axis, None, None)`` shards them the same way but keys a second
-    compile of the step."""
+    ``P(axis, None, None, None)`` shards them the same way but keys a
+    second compile of the step."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     axis = spec[0]
     n = (dict(zip(mesh.axis_names, mesh.devices.shape)).get(axis, 1)
         if axis else 1)
     for path, pool in cache.pools.items():
-        p = pool.shape[0]
-        pad = (-p) % max(n, 1)
+        pad = (-pool.shape[0]) % max(n, 1)
         if pad:
-            pool = jnp.pad(pool, ((0, pad), (0, 0), (0, 0)))
+            pool = jnp.pad(pool, ((0, pad),) + ((0, 0),) * (pool.ndim - 1))
         cache.pools[path] = jax.device_put(pool,
                                            NamedSharding(mesh, P(axis)))

@@ -19,14 +19,22 @@ Every step returns a :class:`ResultTokens`: tokens + validity + lengths
 packed into **one** array — one device→host copy per step is much
 faster than three (the JetStream observation).
 
+The step works on the page pools in place: it donates the pools and the
+lane pools, its layer loop reads each layer's K/V of every slot from the
+pool (``PageLayout.layer_kv``), and only the one row per slot and layer
+that the token wrote leaves the loop, to be written into the donated
+pools (``PageLayout.scatter_written``).  No dense copy of the cache is
+made.
+
 Tracing: ``insert`` records the profiler spans ``serve.prefill`` (args
 ``rid``, ``tokens``) and ``serve.cache_insert`` (``pages``), ``step``
 records ``serve.step`` (``live``) around ``serve.fetch``, the host read
 of the packed result; they record nothing while the profiler is off.
-Inside the step program, ``jax.named_scope`` names ``cache_gather``,
-``sample`` and ``cache_scatter`` (``decode_step`` names ``attention``,
-``mlp`` and ``head``); :meth:`SlotEngine.step_hlo_text` returns the
-compiled step, whose op metadata carries those scopes.
+Inside the step program, ``jax.named_scope`` names ``cache_gather``
+(each layer's pool read), ``sample`` and ``cache_scatter`` (the row
+writes; ``decode_step`` names ``attention``, ``mlp`` and ``head``);
+:meth:`SlotEngine.step_hlo_text` returns the compiled step, whose op
+metadata carries those scopes.
 """
 from __future__ import annotations
 
@@ -107,7 +115,8 @@ class SlotEngine:
 
         self._prefill = jax.jit(functools.partial(dec.prefill, cfg=cfg),
                                 static_argnames=("max_len",))
-        self._step_fn = jax.jit(self._build_step())
+        # the step donates its pools and lanes: the row writes alias them
+        self._step_fn = jax.jit(self._build_step(), donate_argnums=(5, 6))
         self._base_key = jax.random.PRNGKey(self.serve_cfg.seed)
         self._step_count = 0
         self._prefill_count = 0
@@ -160,11 +169,10 @@ class SlotEngine:
                 .astype(jnp.int32))
 
         def step(params, tokens, pos, active, table, pools, lanes, key):
-            with jax.named_scope("cache_gather"):
-                views = lay.gather_views(pools, table)
-            cache: Dict[str, Any] = _nest({**views, **lanes})
+            cache: Dict[str, Any] = _nest({**lay.layer_inputs(), **lanes})
             cache["pos"] = pos
-            logits, new_cache = dec.decode_step(params, tokens, cache, cfg)
+            logits, new_cache = dec.decode_step(
+                params, tokens, cache, cfg, kv=lay.layer_kv(pools, table))
             flat_new = _flatten_cache(new_cache)
             with jax.named_scope("cache_scatter"):
                 pools2 = lay.scatter_written(

@@ -1,15 +1,21 @@
 """Continuous-batching serving: paged cache, slot engine, async server.
 
 The load-bearing claims, each tested directly:
-  * the Pallas paged gather is bit-identical to its jnp twin;
+  * the paged gather reads one layer of the pool through the page table,
+    and the row scatter writes one row per slot and layer;
   * the page pool's host accounting (alloc/free/oversubscription) is sound;
   * the slot engine reproduces sequential ``DecodeEngine.generate``
     token-for-token under staggered insert/evict, for every cache family
     (dense, SWA, SSM, hybrid) — with exactly ONE decode compilation;
+  * its step works on the pools in place: the pools are donated, no
+    dense copy of the cache is made, and a step writes one row per live
+    slot into each pool and nothing else;
   * the async server delivers the same bit-identical outputs to many
     submitting threads at once;
   * page placement flows through the partition solver.
 """
+import dataclasses
+import re
 import threading
 
 import numpy as np
@@ -18,8 +24,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
-from repro.kernels.paged import (paged_gather, paged_gather_pallas,
-                                 paged_scatter_token)
+from repro.kernels.paged import paged_gather, paged_scatter_token
 from repro.models import init_params, split
 from repro.serve import (ContinuousServer, DecodeEngine, PagedKVCache,
                          ServeConfig, SlotEngine, solve_page_placement)
@@ -42,14 +47,28 @@ def make_prompts(cfg, reqs, seed=0):
 # paged gather/scatter kernel
 # ---------------------------------------------------------------------------
 
-def test_paged_gather_pallas_matches_jnp():
-    rng = np.random.default_rng(0)
-    pool = jnp.asarray(rng.standard_normal((9, 8, 32)).astype(np.float32))
-    table = jnp.asarray(rng.integers(0, 9, (3, 4)).astype(np.int32))
-    want = paged_gather(pool, table)
-    got = paged_gather_pallas(pool, table, interpret=True)
-    assert want.shape == (3, 32, 32)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_gather_reads_one_layer(layer):
+    """A layer's view is its rows of each mapped page, in table order."""
+    rng = np.random.default_rng(1)
+    pool = rng.standard_normal((9, 3, 8, 16)).astype(np.float32)
+    table = rng.integers(0, 9, (3, 4)).astype(np.int32)
+    want = pool[:, layer][table].reshape(3, 32, 16)
+    got = paged_gather(jnp.asarray(pool), jnp.asarray(table),
+                       jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_paged_scatter_token_writes_every_layers_row():
+    pool = jnp.zeros((4, 3, 8, 16))
+    vals = jnp.arange(1, 4, dtype=jnp.float32)[None, :, None] * jnp.ones(
+        (2, 3, 16))
+    out = np.asarray(paged_scatter_token(pool, jnp.array([1, 3]),
+                                         jnp.array([0, 7]), vals))
+    for layer in range(3):
+        assert (out[1, layer, 0] == layer + 1).all()
+        assert (out[3, layer, 7] == layer + 1).all()
+    assert out.sum() == 2 * 16 * (1 + 2 + 3)  # nothing else written
 
 
 def test_paged_scatter_token_writes_one_row():
@@ -182,6 +201,78 @@ def test_slot_engine_no_recompile_across_churn():
         eng.evict(slot)
     assert eng.decode_compiles == 1
     assert eng.prefill_compiles == 1       # one prompt length -> one entry
+
+
+#: tiny configs whose whole cache view cannot share an element count with
+#: a per-layer tensor (L * kv differs from the attention's Hq * dh)
+STEP_CONFIGS = {"dense": ("granite-8b", dict(n_layers=3)),
+                "hybrid": ("zamba2-1.2b", dict(n_layers=6))}
+
+
+def _shapes(text):
+    return [tuple(int(d) for d in m.group(1).split(",") if d)
+            for m in re.finditer(r"\b(?:bf16|f16|f32|s32|u32|pred)\[([\d,]*)\]",
+                                 text)]
+
+
+@pytest.mark.parametrize("kind", sorted(STEP_CONFIGS))
+def test_slot_step_in_place_on_pools(kind):
+    """The compiled step aliases every pool to an output, and no
+    instruction holds the whole (stack, C, S, kv) view of a paged leaf or
+    the stacked updated cache (the same element count)."""
+    arch, over = STEP_CONFIGS[kind]
+    cfg = dataclasses.replace(get_config(arch).reduced(), **over)
+    params, _ = split(init_params(jax.random.PRNGKey(0), cfg))
+    eng = SlotEngine(params, cfg, capacity=3, max_context=32, page_size=8)
+    eng.insert(np.arange(12, dtype=np.int32) % cfg.vocab, max_new_tokens=4)
+    eng.step()
+    text = eng.step_hlo_text()
+    lay = eng.cache.layout
+    assert lay.paged
+
+    header = text.splitlines()[0]
+    aliased = {int(p) for p in re.findall(r"\}: \((\d+), \{\}", header)}
+    params_sig = header.split("entry_computation_layout={(", 1)[1]
+    params_sig = params_sig.split(")->", 1)[0]
+    arg_shapes = _shapes(params_sig)
+    for path, pool in eng.cache.pools.items():
+        at = [i for i, s in enumerate(arg_shapes) if s == pool.shape]
+        assert at and set(at) <= aliased, (path, pool.shape, header)
+
+    for path, (stack, feat, _) in lay.paged:
+        whole = stack * lay.capacity * lay.seq_len * feat
+        big = [s for s in _shapes(text) if int(np.prod(s)) == whole]
+        assert not big, (path, big)
+
+
+def test_slot_step_writes_one_row_per_live_slot():
+    """One step changes, in each pool, exactly the row each live slot
+    writes (in every layer) and nothing else; an inactive slot writes
+    only the scratch page."""
+    cfg, params = setup_arch("granite-8b")
+    eng = SlotEngine(params, cfg, capacity=3, max_context=32, page_size=8)
+    lay = eng.cache.layout
+    p = np.arange(11, dtype=np.int32) % cfg.vocab
+    live = [eng.insert(p, max_new_tokens=6)[0],
+            eng.insert(p[:7], max_new_tokens=6)[0]]
+    assert eng.free_slots()                   # one slot stays inactive
+    eng.step()                                # positions 11 and 7 written
+    for _ in range(2):
+        before = {k: np.asarray(v).copy() for k, v in eng.cache.pools.items()}
+        table = eng.cache.table.copy()
+        pos = {s: eng.position(s) for s in live}
+        eng.step()
+        for path, old in before.items():
+            new = np.asarray(eng.cache.pools[path])
+            changed = {tuple(int(i) for i in at) for at in
+                       zip(*np.nonzero((new != old).any(axis=-1)))}
+            want = {(int(table[s, pos[s] // lay.page_size]), layer,
+                     pos[s] % lay.page_size)
+                    for s in live for layer in range(old.shape[1])}
+            assert want <= changed, (path, want, changed)
+            assert {at[0] for at in changed - want} <= {lay.scratch_page}
+        assert {s: eng.position(s) for s in live} == {
+            s: q + 1 for s, q in pos.items()}
 
 
 def test_slot_engine_rejects_oversized_request():
