@@ -4,14 +4,15 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-from . import counts, trace
+from . import trace
 
 
 @dataclasses.dataclass(frozen=True)
 class DecodeWork:
     runs: trace.ProgramRuns         # the decode-step program in the trace
     flops: int                      # model FLOPs of the live tokens
-    bytes: int                      # least bytes (``counts.decode_needs``)
+    bytes: int                      # least bytes (the architecture's
+                                    # ``decode_needs``)
     tokens: int                     # live slot-steps
 
 
@@ -38,5 +39,6 @@ def decode_work(run) -> Optional[DecodeWork]:
         for k in range(1, n):
             if ta <= r.first + k * step < tb:
                 contexts.append(r.request.prompt_len + k)
-    flops, nbytes = counts.decode_needs(run.dims, runs.count, contexts)
+    flops, nbytes = run.cell.architecture().decode_needs(
+        run.dims, runs.count, contexts)
     return DecodeWork(runs, flops, nbytes, len(contexts))

@@ -14,7 +14,7 @@ import tempfile
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from . import counts, spec, trace as trace_mod
+from . import spec, trace as trace_mod
 from .peaks import peaks_for
 
 #: the traced part of a ``--trace 1`` window, at most
@@ -133,8 +133,9 @@ class Run:
     compiles_in_window: int = 0
 
     @property
-    def dims(self) -> counts.DenseDims:
-        return counts.dense_dims(self.cell.config)
+    def dims(self):
+        """The configuration's shape, as its architecture counts it."""
+        return self.cell.architecture().dims(self.cell.config)
 
 
 def device_info(chips: int) -> Dict[str, Any]:

@@ -22,7 +22,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from . import counts, traffic
+from . import traffic
 
 #: how long a request due in the window may take to finish after the
 #: window closes before it counts as never served
@@ -46,33 +46,25 @@ class Record:
                 and len(self.tokens) == self.request.output_len)
 
 
-def model_config(conf: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    dims = counts.dense_dims(conf)
-    return ModelConfig(
-        name=conf["name"], family="dense", n_layers=dims.layers,
-        d_model=dims.d, n_heads=dims.heads, n_kv_heads=dims.kv_heads,
-        d_ff=dims.ff, vocab=dims.vocab, head_dim=dims.head_dim,
-        swa_window=conf.get("sliding_window"),
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        dtype=conf["torch_dtype"])
-
-
 def jax_seed(seed: int, salt: int = 0) -> int:
     """A 31-bit seed for JAX from any whole number (JAX keeps only the
     low 32 bits of a larger one)."""
     return int(np.random.default_rng([seed, salt]).integers(0, 2 ** 31 - 1))
 
 
-def make_weights(mcfg, seed: int):
+#: a leaf's rule in ``make_weights``: ones (a norm's gain), else the
+#: standard deviation of a normal around zero
+ONES = "ones"
+
+
+def make_weights(mcfg, seed: int, architecture):
     """Random weights in the served dtype, made on the device by one
-    jitted call: norms 1, the embedding N(0, 0.02^2), every other matrix
-    N(0, 1/fan_in).  The tree's layout comes from the program's own
-    initializer, evaluated for shapes only."""
+    jitted call.  Each leaf's rule is the architecture's
+    ``init_scale(path, leaf)`` where it defines one and returns a rule
+    (``ONES`` or a standard deviation), else the default: norms 1, the
+    embedding N(0, 0.02^2), every other matrix N(0, 1/fan_in).  The
+    tree's layout comes from the program's own initializer, evaluated for
+    shapes only."""
     import jax
     import jax.numpy as jnp
 
@@ -83,10 +75,15 @@ def make_weights(mcfg, seed: int):
     flat, tree = jax.tree_util.tree_flatten_with_path(shapes)
     dtype = jnp.dtype(mcfg.dtype)
 
+    own = getattr(architecture, "init_scale", None)
+
     def scale(path, leaf):
+        rule = own(path, leaf) if own is not None else None
+        if rule is not None:
+            return rule
         name = getattr(path[-1], "key", "")
         if name in ("ln1", "ln2", "final_norm"):
-            return None
+            return ONES
         if name == "embed":
             return 0.02
         return float(leaf.shape[-2]) ** -0.5
@@ -98,7 +95,7 @@ def make_weights(mcfg, seed: int):
         keys = jax.random.split(key, len(flat))
         out = []
         for k, (_, leaf), s in zip(keys, flat, scales):
-            if s is None:
+            if s == ONES:
                 out.append(jnp.ones(leaf.shape, dtype))
             else:
                 out.append(jax.random.normal(k, leaf.shape, dtype)
@@ -151,10 +148,10 @@ def setup(cell, seed: int, seconds: float):
 
     import jax
 
-    mix, conf = cell.traffic, cell.config
-    mcfg = model_config(conf)
+    mix, arch = cell.traffic, cell.architecture()
+    mcfg = arch.program_config(cell.config)
     t = [time.perf_counter()]
-    params = jax.block_until_ready(make_weights(mcfg, seed))
+    params = jax.block_until_ready(make_weights(mcfg, seed, arch))
     t.append(time.perf_counter())
     requests = traffic.serve_requests(mix, seconds, seed, mcfg.vocab)
     engine = SlotEngine(

@@ -4,15 +4,22 @@
 and metrics.  Everything that belongs to one of them is a file of its
 own, found by its name:
 
-* a configuration: the JSON file its entry names (``file``), and the
-  plain reference it names (``"reference"`` -> ``bench/references/<name>.py``;
-  a mix that computes something else names its own);
+* a configuration: the JSON file its entry names (``file``); the plain
+  reference it names (``"reference"`` -> ``bench/references/<name>.py``;
+  a mix that computes something else names its own); and under the same
+  name its architecture, ``bench/architectures/<name>.py``, which maps the
+  file onto the program (``program_config(conf)``), gives the counts the
+  decode metrics read (``dims(conf)``, ``decode_needs(dims, steps,
+  contexts)``) and may give ``init_scale(path, leaf)`` for weights the
+  default rule does not cover (``serve.make_weights``).  The two stay
+  apart because a reference imports nothing of the program;
 * a traffic mix: ``bench/traffic/<traffic>.json``;
 * a metric, end-to-end or per-layer: ``bench/metrics/<name>.py``, which
   defines ``read(run)`` returning a number, or None where the run has
   nothing for it to read.
 
-So a new configuration, mix or metric is new files and new entries.
+So a new configuration, architecture, mix or metric is new files and new
+entries.
 """
 from __future__ import annotations
 
@@ -20,6 +27,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 from typing import Any, Callable, Dict, List, Mapping, Optional
 
 BENCH_DIR = "bench"
@@ -52,13 +60,26 @@ class Cell:
         return load_module(os.path.join(self.root, BENCH_DIR, "references",
                                         name + ".py"))
 
+    def architecture(self):
+        """The configuration's architecture module."""
+        return load_module(architecture_path(self.root, self.config),
+                           prefix="bench_architecture_")
 
-def load_module(path: str):
-    name = "bench_" + os.path.basename(path)[:-3].replace(".", "_")
+
+def architecture_path(root: str, config: Mapping) -> str:
+    return os.path.join(root, BENCH_DIR, "architectures",
+                        config["reference"] + ".py")
+
+
+def load_module(path: str, prefix: str = "bench_"):
+    name = prefix + os.path.basename(path)[:-3].replace(".", "_")
     spec = importlib.util.spec_from_file_location(name, path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
+    # registered, as an import would be: a dataclass defined in the module
+    # looks its module up by name
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
 
@@ -84,6 +105,11 @@ def load_cell(root: str, name: str) -> Cell:
     confs = {c["name"]: c for c in bench["configs"]}
     with open(os.path.join(root, confs[w["config"]]["file"])) as f:
         config = json.load(f)
+    arch = architecture_path(root, config)
+    if not os.path.isfile(arch):
+        raise FileNotFoundError(
+            f"configuration {w['config']!r} names the architecture "
+            f"{config['reference']!r}, and {arch} does not exist")
     with open(os.path.join(root, BENCH_DIR, "traffic",
                            w["traffic"] + ".json")) as f:
         traffic = json.load(f)

@@ -1,7 +1,8 @@
 """The least time the decode steps in the traced window could take on
 the chip (their model FLOPs at peak, or their least bytes at HBM
-bandwidth, whichever is longer; ``benchlib.counts.decode_needs``) over
-the decode-step program's measured device time, in percent."""
+bandwidth, whichever is longer; the ``decode_needs`` of the
+configuration's architecture, ``bench/architectures/``) over the
+decode-step program's measured device time, in percent."""
 from benchlib.decode_work import decode_work
 
 

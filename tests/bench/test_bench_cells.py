@@ -10,7 +10,7 @@ import _bench_path  # noqa: F401
 import numpy as np
 import pytest
 
-from benchlib import harness, spec
+from benchlib import decode_work, harness, serve, spec, trace
 
 TINY = {
     "name": "tiny", "num_hidden_layers": 2, "hidden_size": 64,
@@ -38,9 +38,9 @@ SEED = 2 ** 31 + 12345
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
     """A checkout holding only its own BENCHMARK.json, configuration,
-    mixes, metric readers and references."""
+    mixes, metric readers, references and architectures."""
     r = tmp_path_factory.mktemp("checkout")
-    for d in ("metrics", "references"):
+    for d in ("metrics", "references", "architectures"):
         shutil.copytree(os.path.join(_bench_path.BENCH, d), r / "bench" / d)
     (r / "bench" / "configs").mkdir()
     (r / "bench" / "traffic").mkdir()
@@ -161,3 +161,108 @@ def test_float8_control_fails_the_limits(root, capsys):
         bound = TINY["limits"][limit]
         assert all(r["program"] <= bound < r["control"] for r in rows), rows
         assert np.all([r["number"] == limit for r in rows])
+
+
+#: an architecture the repository does not have: a tiny mixture of
+#: experts, mapped onto the program's existing ``family="moe"``, with
+#: counts of its own
+MOE_ARCHITECTURE = """
+from __future__ import annotations
+
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class MoeDims:
+    experts: int
+    top_k: int
+
+
+def program_config(conf):
+    from repro.configs.base import ModelConfig
+
+    return ModelConfig(
+        name=conf["name"], family="moe",
+        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
+        n_heads=conf["num_attention_heads"],
+        n_kv_heads=conf["num_key_value_heads"],
+        d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
+        head_dim=conf["head_dim"], n_experts=conf["num_local_experts"],
+        top_k=conf["num_experts_per_tok"],
+        tie_embeddings=conf["tie_word_embeddings"],
+        dtype=conf["torch_dtype"])
+
+
+def dims(conf):
+    return MoeDims(conf["num_local_experts"], conf["num_experts_per_tok"])
+
+
+def decode_needs(dims, steps, contexts):
+    return len(list(contexts)) * dims.top_k, steps * dims.experts
+
+
+def init_scale(path, leaf):
+    return 1.0 if getattr(path[-1], "key", "") == "router" else None
+"""
+
+
+def checkout_with(root, tmp_path, conf):
+    """A copy of the ``root`` checkout with one more configuration and a
+    cell serving it the tiny chat mix: new files and entries only."""
+    r = tmp_path / "checkout"
+    shutil.copytree(root, r)
+    (r / "bench" / "configs" / f"{conf['name']}.json").write_text(
+        json.dumps(conf))
+    bench = json.loads((r / "BENCHMARK.json").read_text())
+    bench["configs"].append(
+        {"name": conf["name"], "source": "test", "reduced": [],
+         "file": f"bench/configs/{conf['name']}.json", "why": "test"})
+    cell = conf["name"] + ".chat"
+    bench["workloads"].append({"name": cell, "config": conf["name"],
+                               "traffic": "tiny_chat", "chips": 1,
+                               "why": "test"})
+    (r / "BENCHMARK.json").write_text(json.dumps(bench))
+    return str(r), cell
+
+
+def test_new_architecture_needs_only_new_files(root, tmp_path):
+    conf = dict(TINY, name="tiny-moe", reference="tiny_moe",
+                num_local_experts=4, num_experts_per_tok=2)
+    r, name = checkout_with(root, tmp_path, conf)
+    with open(os.path.join(r, "bench", "architectures", "tiny_moe.py"),
+              "w") as f:
+        f.write(MOE_ARCHITECTURE)
+    cell = spec.load_cell(r, name)
+    engine, server, params, requests = serve.setup(cell, SEED, 2.0)
+    try:
+        assert engine.cfg.family == "moe"
+        assert (engine.cfg.n_experts, engine.cfg.top_k) == (4, 2)
+        # the module's rule, N(0, 1), not the default's N(0, 1/64)
+        router = np.asarray(params["layers"]["ffn"]["router"], np.float32)
+        assert router.shape == (2, 64, 4)
+        assert 0.8 < router.std() < 1.25
+        records, t0, t1, _, _ = serve.window(server, requests, 2.0)
+        records = serve.collect(records, t1, backlog=False)
+    finally:
+        serve.free(engine, server)
+    assert len(records) > 5 and all(r.served for r in records)
+    # the decode metrics count through the module too
+    run = harness.Run(cell=cell, kind="serve", window_s=t1 - t0, peaks=None,
+                      records=records)
+    steps = [trace.Event(f"jit_step({i})", 10 * i, 10 * i + 5)
+             for i in range(3)]
+    run.trace = trace.Trace(window=(0, 100), host=[], devices=[
+        trace.Device("/device:TPU:0", steps, [])])
+    run.trace_host = (t0, t1 + serve.DRAIN_S)
+    w = decode_work.decode_work(run)
+    assert w.tokens > 0
+    assert (w.flops, w.bytes) == (2 * w.tokens, 3 * 4)
+
+
+def test_configuration_without_architecture_fails_at_load_cell(root,
+                                                                tmp_path):
+    r, name = checkout_with(root, tmp_path,
+                            dict(TINY, name="tiny-x", reference="tiny_x"))
+    with pytest.raises(FileNotFoundError,
+                       match=r"bench/architectures/tiny_x\.py"):
+        spec.load_cell(r, name)

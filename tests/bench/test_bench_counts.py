@@ -5,7 +5,7 @@ import os
 import _bench_path  # noqa: F401
 import pytest
 
-from benchlib import counts, peaks
+from benchlib import counts, peaks, spec
 
 CONFIGS = os.path.join(_bench_path.BENCH, "configs")
 
@@ -41,3 +41,47 @@ def test_unknown_device_kind_is_an_error():
     assert peaks.peaks_for("TPU v5 lite").flops_bf16 == 197e12
     with pytest.raises(KeyError):
         peaks.peaks_for("TPU v9 imaginary")
+
+
+#: the program's configuration of each served cell, as the harness built
+#: it before configurations named their architecture
+PROGRAM_CONFIGS = {
+    "danube.chat": dict(
+        name="danube-1.8b", family="dense", n_layers=24, d_model=2560,
+        n_heads=32, n_kv_heads=8, d_ff=6912, vocab=32000, head_dim=80,
+        swa_window=4096, rope_theta=10000.0, norm_eps=1e-05,
+        tie_embeddings=False, dtype="bfloat16"),
+    "granite.batch": dict(
+        name="granite-8b-9l", family="dense", n_layers=9, d_model=4096,
+        n_heads=32, n_kv_heads=8, d_ff=14336, vocab=49152, head_dim=128,
+        swa_window=None, rope_theta=10000000.0, norm_eps=1e-05,
+        tie_embeddings=True, dtype="bfloat16")}
+
+
+@pytest.mark.parametrize("cell", sorted(PROGRAM_CONFIGS))
+def test_architecture_gives_the_same_program_config(cell):
+    from repro.configs.base import ModelConfig
+
+    c = spec.load_cell(_bench_path.REPO, cell)
+    got = c.architecture().program_config(c.config)
+    assert got == ModelConfig(**PROGRAM_CONFIGS[cell])
+
+
+#: (FLOPs, bytes) of 3 steps over contexts 100, 1500 and 2047, and of one
+#: step with no live slot, as ``counts.decode_needs`` gave them
+DECODE_NEEDS = {
+    "danube.chat": ((11_391_221_760, 10_719_959_040), (0, 3_498_562_560)),
+    "granite.batch": ((13_523_337_216, 13_120_585_728),
+                      (0, 4_328_677_376))}
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_NEEDS))
+def test_architecture_gives_the_same_decode_needs(cell):
+    c = spec.load_cell(_bench_path.REPO, cell)
+    arch = c.architecture()
+    d = arch.dims(c.config)
+    got = (arch.decode_needs(d, 3, [100, 1500, 2047]),
+           arch.decode_needs(d, 1, []))
+    assert got == DECODE_NEEDS[cell]
+    assert got[0] == counts.decode_needs(counts.dense_dims(c.config), 3,
+                                         [100, 1500, 2047])
